@@ -17,11 +17,12 @@ ways on the smoke LM:
     ``lax.scan`` decode step, zero per-layer dispatches). The loop-vs-scan
     summary row reports decode-step latency, first-run trace/compile time,
     tokens/s, and the ``tokens_match`` parity bit (bit-exactness contract);
-  * ``sharded``    - the compressed server column-sharded over a forced
-    4-device host macro mesh (run in a subprocess so the device count can
-    be set before jax imports). On CPU fake devices this measures the
-    orchestration overhead, not a speedup - the row's purpose is the
-    contract: tokens bit-identical to single-device (``tokens_match``);
+  * ``sharded``    - the compressed server column-sharded over a 4-device
+    macro mesh of the devices this process has (reported as not run with
+    fewer; on a CPU host ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
+    gives four). On CPU fake devices this measures the orchestration
+    overhead, not a speedup - the row's purpose is the contract: tokens
+    bit-identical to single-device (``tokens_match``);
   * ``spec``       - self-speculative decode with the LAYERSKIP draft
     family: the draft runs the nnz-ranked top-``SPEC_KEEP`` fraction of
     the TARGET envelope's sublayers (no second packing) and proposes k
@@ -55,18 +56,16 @@ all-gather-aware prediction (``serve_gap(..., n_devices=4)``), so the
 collective's modeled share is confronted with the measured step cost.
 
 Packings are cached as serving artifacts under one shared directory
-(``MARS_BENCH_ARTIFACTS``, default ``/tmp/mars-bench-artifacts``): the
-subprocess rows boot via ``serve.deployed.load_artifact`` instead of
-re-packing from scratch, and repeat benchmark runs (or CI smokes pointed at
-the same directory) skip the search+quantize+prune+pack pipeline entirely.
+(``MARS_BENCH_ARTIFACTS``, default ``bench-artifacts/`` in the checkout,
+git-ignored): repeat benchmark runs boot via
+``serve.deployed.load_artifact`` and skip the search+quantize+prune+pack
+pipeline entirely.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -84,7 +83,8 @@ from repro.launch.serve import prefix_skew_trace, synthetic_trace
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_serve.json")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ART_ROOT = os.environ.get("MARS_BENCH_ARTIFACTS", "/tmp/mars-bench-artifacts")
+ART_ROOT = os.environ.get("MARS_BENCH_ARTIFACTS",
+                          os.path.join(REPO, "bench-artifacts"))
 
 ARCH = "yi-6b"
 N_REQUESTS = 12
@@ -156,7 +156,7 @@ def _cached_packing(name: str, cfg, build_fn, draft: bool = False,
                     want: dict | None = None):
     """Load a packed ServingParams from the shared artifact dir, or build
     it ONCE with ``build_fn() -> (sp, draft_sp_or_None, extra)`` and save
-    it there - subprocess rows and repeat runs boot without re-packing.
+    it there - repeat runs boot without re-packing.
 
     ``want`` pins the packing constants the caller is about to report
     (sparsities, forced tile; the arch is always pinned): a cached
@@ -192,7 +192,7 @@ def _row(name: str, j: dict) -> dict:
 
 def _shard_packing(cfg):
     """The 16x16-tile packing the sharded row serves, cached as a shared
-    artifact so the subprocess boots it instead of re-packing."""
+    artifact so repeat runs boot it instead of re-packing."""
 
     def build():
         params = registry.model_fns(cfg).init_params(cfg,
@@ -206,22 +206,26 @@ def _shard_packing(cfg):
                                  "target_sparsity": TARGET_SPARSITY})[0]
 
 
-def sharded_worker():
-    """Runs inside a subprocess with SHARD_DEVICES forced host devices:
-    serves the benchmark trace single-device and macro-sharded, checks
-    bit-identical tokens, prints the sharded report JSON on the last line.
-    Boots the packing from the shared artifact dir (the parent process
-    already built and saved it - no re-packing here)."""
+def _sharded_report(cfg):
+    """The compressed server column-sharded over ``SHARD_DEVICES`` devices
+    of THIS process, against single-device serving on the same trace.
+
+    The row runs on the devices present and is reported as not run when
+    there are fewer than ``SHARD_DEVICES`` - it never moves to another
+    backend behind the caller's back (on a CPU host,
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` provides them)."""
+    n = len(jax.devices())
+    if n < SHARD_DEVICES:
+        return {"not_run": f"needs {SHARD_DEVICES} devices, found {n}"}
     from repro.launch.shardings import macro_mesh
 
-    cfg = registry.get_smoke_config(ARCH, dtype="float32")
     spc = _shard_packing(cfg)
     trace_fn = lambda: synthetic_trace(cfg, N_REQUESTS, MAX_PROMPT, MAX_NEW)
     single = _serve(cfg, spc, True, trace_fn, repeats=1)
 
     mesh = macro_mesh(SHARD_DEVICES)
     sps = DP.shard(spc, mesh)
-    n_sharded = sum(1 for dw in sps.deployed().values() if dw.mesh is not None)
+    n_sharded = sum(dw.sharded for dw in sps.deployed().values())
     srv = BatchServer(cfg, sps, ServeConfig(),
                       BatchConfig(n_slots=4, block_size=8, n_blocks=64),
                       continuous=True, mesh=mesh)
@@ -234,30 +238,12 @@ def sharded_worker():
     out["n_sharded_projections"] = n_sharded
     out["tile"] = list(SHARD_TILE)
     out["tokens_match_single_device"] = match
-    print(json.dumps(out))
-
-
-def _sharded_report():
-    """Spawn the worker with the forced device count (XLA_FLAGS must be set
-    before jax imports, so it cannot run in this process)."""
-    env = dict(os.environ)
-    # forced host devices only exist on the CPU backend: pin the platform
-    # (else a GPU host's backend wins and macro_mesh(4) has 1 device) and
-    # append to - don't clobber - any flags the caller set
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = " ".join(
-        ([env["XLA_FLAGS"]] if env.get("XLA_FLAGS") else [])
-        + [f"--xla_force_host_platform_device_count={SHARD_DEVICES}"])
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(REPO, "src"), REPO]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    r = subprocess.run(
-        [sys.executable, "-c",
-         "from benchmarks.serve_bench import sharded_worker; sharded_worker()"],
-        capture_output=True, text=True, env=env, cwd=REPO, timeout=1200)
-    if r.returncode != 0:
-        raise RuntimeError(f"sharded worker failed:\n{r.stdout}\n{r.stderr}")
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    # the all-gather-aware prediction (perf_model's ring collective at every
+    # column-sharded projection) against the sharded run's fenced tpot p50
+    out["sim_vs_measured"] = obs_gap.serve_gap(
+        cfg, float(out["tpot"]["p50"]), TARGET_SPARSITY,
+        n_devices=SHARD_DEVICES)
+    return out
 
 
 def _gateway_report(cfg, spc):
@@ -334,7 +320,6 @@ def run():
         want={"target_sparsity": TARGET_SPARSITY,
               "draft_sparsity": SPEC_DRAFT_SPARSITY})
     schedule_tile = list(meta.get("tile", []))
-    _shard_packing(cfg)  # warm the artifact the sharded subprocess boots
 
     trace_fn = lambda: synthetic_trace(cfg, N_REQUESTS, MAX_PROMPT, MAX_NEW)
 
@@ -379,14 +364,7 @@ def run():
         "compressed_scan": scan_rep,
         "spec": spec_rep,
     }
-    sharded = _sharded_report()
-    # sharded gap: the all-gather-aware prediction (perf_model's ring
-    # collective at every column-sharded projection) against the sharded
-    # run's fenced tpot p50 - the measured anchor for the 7x sharded
-    # regression ROADMAP tracks
-    sharded["sim_vs_measured"] = obs_gap.serve_gap(
-        cfg, float(sharded["tpot"]["p50"]), TARGET_SPARSITY,
-        n_devices=SHARD_DEVICES)
+    sharded = _sharded_report(cfg)
     loop_vs_scan = {
         # per-decode-step latency: all slots advance one token per step,
         # so tpot is the step cost; the scan runtime compiles the layer
@@ -526,9 +504,13 @@ def run():
         if r["name"] == "serve_spec":
             r["acceptance_rate"] = spec_summary["acceptance_rate"]
             r["tokens_match_target"] = spec_match
-    srow = _row("sharded_macro%d" % SHARD_DEVICES, sharded)
-    srow["tokens_match"] = sharded["tokens_match_single_device"]
-    rows.append(srow)
+    if "not_run" in sharded:
+        rows.append({"name": "serve_sharded_macro%d" % SHARD_DEVICES,
+                     "not_run": sharded["not_run"]})
+    else:
+        srow = _row("sharded_macro%d" % SHARD_DEVICES, sharded)
+        srow["tokens_match"] = sharded["tokens_match_single_device"]
+        rows.append(srow)
     rows.append({"name": "serve_loop_vs_scan", **loop_vs_scan})
     rows.append({"name": "serve_spec_vs_scan", **spec_summary})
     rows.append({"name": "serve_prefix_skew", **prefix_summary})
@@ -539,16 +521,17 @@ def run():
         "predicted_us": round(sim_gap["predicted_s"] * 1e6, 2),
         "measured_us": round(sim_gap["measured_s"] * 1e6, 2),
     })
-    sharded_gap = sharded["sim_vs_measured"]
-    rows.append({
-        "name": "serve_sharded_sim_vs_measured",
-        "gap": sharded_gap["sim_vs_measured"],
-        "n_devices": SHARD_DEVICES,
-        "collective_share": sharded_gap["predicted_phase_shares"].get(
-            "collective", 0.0),
-        "predicted_us": round(sharded_gap["predicted_s"] * 1e6, 2),
-        "measured_us": round(sharded_gap["measured_s"] * 1e6, 2),
-    })
+    if "not_run" not in sharded:
+        sharded_gap = sharded["sim_vs_measured"]
+        rows.append({
+            "name": "serve_sharded_sim_vs_measured",
+            "gap": sharded_gap["sim_vs_measured"],
+            "n_devices": SHARD_DEVICES,
+            "collective_share": sharded_gap["predicted_phase_shares"].get(
+                "collective", 0.0),
+            "predicted_us": round(sharded_gap["predicted_s"] * 1e6, 2),
+            "measured_us": round(sharded_gap["measured_s"] * 1e6, 2),
+        })
     rows.append({
         "name": "serve_continuous_speedup",
         "vs_static": report["speedup_continuous_vs_static"],

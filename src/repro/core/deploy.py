@@ -47,7 +47,8 @@ class DeployedWeight:
     then permuted into device order (equal-cardinality LPT shards), laid
     out over the mesh's ``macro`` axis, and carries a ``col_inv`` index
     that restores the original column order after the sharded kernel's
-    all-gather.
+    all-gather. After ``replicate_weight`` it holds the mesh too, with
+    every device holding the whole packing and no ``col_inv``.
     """
 
     packed: List[dict]  # one kernel dict per stacked layer
@@ -59,6 +60,11 @@ class DeployedWeight:
     @property
     def density(self) -> float:
         return float(np.mean([p["density"] for p in self.packed]))
+
+    @property
+    def sharded(self) -> bool:
+        """Block columns split over the mesh (not replicated on it)."""
+        return self.mesh is not None and "col_inv" in self.packed[0]
 
     @property
     def tile(self) -> tuple:
@@ -199,8 +205,8 @@ def stack_deployed(dws: Sequence[DeployedWeight]) -> StackedWeight:
             f"stack_deployed: non-uniform (go, bk, bn) across layers "
             f"{sorted(shapes)} - repack with a uniform tile "
             "(sched.search uniform mode / compress(uniform=True))")
-    sharded = ref.mesh is not None
-    if sharded and not all("col_inv" in p for p in packed):
+    sharded = ref.mesh is not None and "col_inv" in packed[0]
+    if sharded != all("col_inv" in p for p in packed):
         raise ValueError("stack_deployed: sharded stack missing col_inv")
     nnz_max = max(int(np.asarray(p["row_idx"]).shape[1]) for p in packed)
 
@@ -229,6 +235,12 @@ def stack_deployed(dws: Sequence[DeployedWeight]) -> StackedWeight:
                              put("row_idx", row_idx), put("nnz", nnz),
                              ref.d_in, ref.d_out, ref.bits,
                              col_inv=put("col_inv", col_inv), mesh=ref.mesh)
+    if ref.mesh is not None:  # replicated on every device of the mesh
+        put = lambda v: jax.device_put(jnp.asarray(v),
+                                       NamedSharding(ref.mesh, P()))
+        return StackedWeight(put(blocks), put(scales), put(row_idx),
+                             put(nnz), ref.d_in, ref.d_out, ref.bits,
+                             mesh=ref.mesh)
     return StackedWeight(jnp.asarray(blocks), jnp.asarray(scales),
                          jnp.asarray(row_idx), jnp.asarray(nnz),
                          ref.d_in, ref.d_out, ref.bits)
@@ -236,21 +248,38 @@ def stack_deployed(dws: Sequence[DeployedWeight]) -> StackedWeight:
 
 def fit_tile(d_in: int, d_out: int, bk: int, bn: int) -> tuple:
     """Largest (bk, bn) at most the requested tile that exactly divides
-    (d_in, d_out) - ``pack_bsr`` requires exact tiling."""
-    return (_largest_divisor(d_in, bk), _largest_divisor(d_out, bn))
+    (d_in, d_out) - ``pack_bsr`` requires exact tiling - with both edges
+    multiples of the backend's tile quantum (``ops.tile_quantum``: the
+    compiled TPU kernel needs lane-aligned blocks). Raises, naming the
+    tile, when no such tile exists."""
+    q = ops.tile_quantum()
+    fit = (_largest_divisor(d_in, bk, q), _largest_divisor(d_out, bn, q))
+    if None in fit:
+        raise ValueError(
+            f"tile {bk}x{bn}: no {q}-aligned tile at most that size divides "
+            f"({d_in}, {d_out}) - the BSR kernel cannot run it here")
+    return fit
 
 
-def _largest_divisor(n: int, at_most: int) -> int:
+def _largest_divisor(n: int, at_most: int, quantum: int = 1):
+    """Largest multiple of ``quantum`` that divides ``n`` and is at most
+    ``at_most``; None when there is none."""
     for d in range(min(at_most, n), 0, -1):
-        if n % d == 0:
+        if n % d == 0 and d % quantum == 0:
             return d
-    return 1
+    return None
 
 
 def deploy_weight(w, cim: CIMConfig, bk: int = 128, bn: int = 128,
                   target_sparsity: Optional[float] = None) -> DeployedWeight:
     """Quantize + prune + pack a (d_in, d_out) or stacked (L, d_in, d_out)
-    master weight for serving."""
+    master weight for serving.
+
+    Pruning and quantization run in float32 on the weight's device; only
+    the int8 levels cross to the host, where ``pack_bsr`` builds the packing.
+    The packed arrays stay in host memory (numpy): the serving runtimes put
+    on the device the form they run (``serve.stacked.stack`` the layer
+    stack), so a packing is never held on the device twice."""
     w = jnp.asarray(w)
     stacked = w if w.ndim == 3 else w[None]
     bits = cim.quant.w_bits
@@ -258,10 +287,10 @@ def deploy_weight(w, cim: CIMConfig, bk: int = 128, bn: int = 128,
           else target_sparsity)
     packed = []
     for wl in stacked:
+        wl = wl.astype(jnp.float32)
         mask = S.prune_mask_2d(wl, bk, bn, ts)
         wq = Q.mars_weight_quant(wl * mask, bits, cim.quant.group_size)
-        packed.append(ops.pack_for_kernel(np.asarray(wq), bits=bits,
-                                          bk=bk, bn=bn))
+        packed.append(ops.pack_for_kernel(wq, bits=bits, bk=bk, bn=bn))
     return DeployedWeight(packed, stacked.shape[-2], stacked.shape[-1], bits)
 
 
@@ -324,6 +353,20 @@ def shard_weight(dw: DeployedWeight, mesh: Mesh, axis: str = MACRO_AXIS,
     return DeployedWeight(packed, dw.d_in, dw.d_out, dw.bits, mesh=mesh)
 
 
+def replicate_weight(dw: DeployedWeight, mesh: Mesh) -> DeployedWeight:
+    """Place a whole packing on every device of ``mesh`` - the serving form
+    of a projection ``shard_weight`` cannot split. Its matmul then runs the
+    kernel on every device under ``shard_map`` (a Mosaic kernel cannot be
+    partitioned by the compiler inside a mesh program)."""
+    if dw.mesh is not None or mesh.size <= 1:
+        return dw
+    rep = NamedSharding(mesh, P())
+    packed = [{**{k: jax.device_put(jnp.asarray(p[k]), rep)
+                  for k in ("blocks", "scales", "row_idx", "nnz")},
+               "density": p["density"]} for p in dw.packed]
+    return DeployedWeight(packed, dw.d_in, dw.d_out, dw.bits, mesh=mesh)
+
+
 def bm_for_rows(rows: int) -> int:
     """Kernel row-tile for an activation row count: the next power of two in
     [8, 128]. A fixed bucket ladder instead of the raw row count means a
@@ -346,20 +389,24 @@ def deployed_matmul(x: jnp.ndarray, dw: DeployedWeight, layer: int = 0,
     each device runs the kernel on its resident block columns only and the
     all-gathered output is un-permuted back to the logical column order.
     """
+    dtype = x.dtype  # the output keeps the caller's activation dtype
     if a_bits:
         x = Q.quantize_activation(x.astype(jnp.float32), a_bits, signed=True)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, dw.d_in)
     bm = bm_for_rows(x2.shape[0])
-    if dw.mesh is not None:
+    if dw.sharded:
         p = dw.packed[layer]
         go, _, _, bn = p["blocks"].shape
         y = ops.bsr_matmul_sharded(x2, p, dw.mesh, bm=bm, interpret=interpret)
         y = jnp.take(y.reshape(-1, go, bn), p["col_inv"], axis=1)
         y = y.reshape(-1, dw.d_out)
+    elif dw.mesh is not None:
+        y = ops.bsr_matmul_sharded(x2, dw.packed[layer], dw.mesh, bm=bm,
+                                   interpret=interpret, axis=None)
     else:
         y = ops.bsr_matmul(x2, dw.packed[layer], bm=bm, interpret=interpret)
-    return y.reshape(*lead, dw.d_out).astype(x.dtype)
+    return y.reshape(*lead, dw.d_out).astype(dtype)
 
 
 def stacked_matmul(x: jnp.ndarray, sw: StackedWeight, layer,
@@ -372,12 +419,17 @@ def stacked_matmul(x: jnp.ndarray, sw: StackedWeight, layer,
     same compiled program. Numerics are bit-identical to
     ``deployed_matmul(x, dw_layer)`` - envelope padding contributes nothing.
     """
+    dtype = x.dtype  # the output keeps the caller's activation dtype
     if a_bits:
         x = Q.quantize_activation(x.astype(jnp.float32), a_bits, signed=True)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, sw.d_in)
     bm = bm_for_rows(x2.shape[0])
-    if sw.mesh is not None:
+    if sw.mesh is not None and sw.col_inv is None:
+        y = ops.bsr_matmul_stacked_sharded(
+            x2, sw.blocks, sw.scales, sw.row_idx, sw.nnz, layer, sw.mesh,
+            bm=bm, interpret=interpret, axis=None)
+    elif sw.mesh is not None:
         go, bn = int(sw.nnz.shape[1]), sw.tile[1]
         y = ops.bsr_matmul_stacked_sharded(
             x2, sw.blocks, sw.scales, sw.row_idx, sw.nnz, layer, sw.mesh,
@@ -389,7 +441,7 @@ def stacked_matmul(x: jnp.ndarray, sw: StackedWeight, layer,
     else:
         y = ops.bsr_matmul_stacked(x2, sw.blocks, sw.scales, sw.row_idx,
                                    sw.nnz, layer, bm=bm, interpret=interpret)
-    return y.reshape(*lead, sw.d_out).astype(x.dtype)
+    return y.reshape(*lead, sw.d_out).astype(dtype)
 
 
 def unshard_weight(dw: DeployedWeight) -> DeployedWeight:
@@ -401,9 +453,10 @@ def unshard_weight(dw: DeployedWeight) -> DeployedWeight:
         return dw
     packed = []
     for p in dw.packed:
-        inv = np.asarray(p["col_inv"])
+        go = np.asarray(p["nnz"]).shape[0]
+        inv = np.asarray(p["col_inv"]) if "col_inv" in p else np.arange(go)
         packed.append({
-            **{k: jnp.asarray(np.asarray(p[k])[inv])
+            **{k: np.asarray(p[k])[inv]
                for k in ("blocks", "scales", "row_idx", "nnz")},
             "density": p["density"],
         })
@@ -414,7 +467,8 @@ def uniform_fit_tile(shapes: Sequence[tuple], bk: int, bn: int) -> tuple:
     """One (bk, bn) for a whole network: the largest tile at most the
     requested one that exactly divides EVERY (d_in, d_out) in ``shapes`` -
     the CIM-Tuner-style network-wide mapping constraint that makes every
-    projection's packing share a hardware-feasible envelope."""
+    projection's packing share a hardware-feasible envelope (and, like
+    :func:`fit_tile`, a multiple of the backend's tile quantum)."""
     if not shapes:
         return (bk, bn)
     gk = 0
@@ -422,7 +476,7 @@ def uniform_fit_tile(shapes: Sequence[tuple], bk: int, bn: int) -> tuple:
     for d_in, d_out in shapes:
         gk = int(np.gcd(gk, int(d_in)))
         gn = int(np.gcd(gn, int(d_out)))
-    return (_largest_divisor(gk, bk), _largest_divisor(gn, bn))
+    return fit_tile(gk, gn, bk, bn)
 
 
 def reference_matmul(x: jnp.ndarray, w, cim: CIMConfig,

@@ -141,12 +141,12 @@ class TenantRuntime:
 
     def _jit(self) -> None:
         c = self.compiles
-        self._prefill = jax.jit(_counted(stacked.prefill_last, c),
-                                static_argnames=("cfg",))
-        self._decode = jax.jit(_counted(stacked.decode_step_paged, c),
-                               static_argnames=("cfg",))
-        self._verify = jax.jit(_counted(stacked.verify_step, c),
-                               static_argnames=("cfg",))
+        self._prefill = stacked.serving_jit(_counted(stacked.prefill_last, c),
+                                            static_argnames=("cfg",))
+        self._decode = stacked.serving_jit(_counted(stacked.decode_step_paged, c),
+                                           static_argnames=("cfg",))
+        self._verify = stacked.serving_jit(_counted(stacked.verify_step, c),
+                                           static_argnames=("cfg",))
 
     @property
     def kv_geometry(self) -> Tuple:

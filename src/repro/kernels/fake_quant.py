@@ -26,7 +26,8 @@ def _kernel(x_ref, o_ref, *, bits: int, signed: bool):
 
 @functools.partial(jax.jit, static_argnames=("bits", "signed", "bm", "bn", "interpret"))
 def fake_quant(x: jnp.ndarray, bits: int, signed: bool = False,
-               bm: int = 256, bn: int = 256, interpret: bool = True) -> jnp.ndarray:
+               bm: int = 256, bn: int = 256, *,
+               interpret: bool) -> jnp.ndarray:
     """Tiled fake-quant; arbitrary leading shape, last dim tiled."""
     shape = x.shape
     flat = x.reshape(-1, shape[-1])

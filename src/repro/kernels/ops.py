@@ -1,7 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels target TPU v5e and are validated in interpret mode per the spec).
+The kernel modules take ``interpret`` as a required argument. These
+wrappers resolve ``interpret=None`` from the backend, in one place: the
+kernels compile for the TPU when JAX runs on one, and run in Pallas
+interpret mode (slow, exact) on any other backend, as in CPU tests.
 """
 from __future__ import annotations
 
@@ -21,7 +23,18 @@ def on_tpu() -> bool:
 
 
 def default_interpret() -> bool:
+    """Interpret mode exactly when the backend is not a TPU."""
     return not on_tpu()
+
+
+def tile_quantum() -> int:
+    """Multiple every BSR tile edge must be on this backend."""
+    return cim_bsr_matmul.tile_quantum(default_interpret())
+
+
+def tile_sizes() -> tuple:
+    """Tile edges the serving tile search offers on this backend."""
+    return cim_bsr_matmul.tile_sizes(default_interpret())
 
 
 # ---------------------------------------------------------------------------
@@ -29,21 +42,20 @@ def default_interpret() -> bool:
 # ---------------------------------------------------------------------------
 
 
-def pack_for_kernel(w_q: np.ndarray, bits: int, bk: int = 128, bn: int = 128
-                    ) -> dict:
+def pack_for_kernel(w_q, bits: int, bk: int = 128, bn: int = 128) -> dict:
     """Take eq.8 output (float levels/2^{b-1}) and produce the kernel's
     int8-blocks + scales + index arrays. Zero blocks are dropped (the CIM
-    skip). Returns a dict of jnp arrays."""
-    scale = 1.0 / (2.0 ** (bits - 1))
-    levels = np.asarray(np.round(np.asarray(w_q, np.float64) / scale), np.int8)
+    skip). The levels are rounded where ``w_q`` lives and only int8 crosses
+    to the host; returns a dict of host (numpy) arrays."""
+    levels = np.asarray(weight_int_levels(jnp.asarray(w_q), bits))
     bsr = pack_bsr(levels, bk, bn)
     go, nnz_max = bsr.row_idx.shape
-    scales = np.full((go, nnz_max), scale, np.float32)
+    scales = np.full((go, nnz_max), 1.0 / (2.0 ** (bits - 1)), np.float32)
     return {
-        "blocks": jnp.asarray(bsr.blocks),
-        "scales": jnp.asarray(scales),
-        "row_idx": jnp.asarray(bsr.row_idx),
-        "nnz": jnp.asarray(bsr.nnz),
+        "blocks": bsr.blocks,
+        "scales": scales,
+        "row_idx": bsr.row_idx,
+        "nnz": bsr.nnz,
         "density": bsr.density,
     }
 
@@ -59,10 +71,12 @@ def bsr_matmul(x, packed: dict, bm: int = 128, interpret: bool | None = None):
 
 def bsr_matmul_sharded(x, packed: dict, mesh, bm: int = 128,
                        interpret: bool | None = None,
-                       axis: str = cim_bsr_matmul.MACRO_AXIS):
+                       axis: str | None = cim_bsr_matmul.MACRO_AXIS):
     """Macro-cluster tensor-parallel bsr_matmul over a column-sharded
     packed dict (see ``core.deploy.shard_weight``). Output columns are in
-    device order - the caller un-permutes with ``packed['col_inv']``."""
+    device order - the caller un-permutes with ``packed['col_inv']``.
+    ``axis=None``: the packing is replicated on the mesh
+    (``core.deploy.replicate_weight``) and the output is in logical order."""
     if interpret is None:
         interpret = default_interpret()
     return cim_bsr_matmul.bsr_matmul_sharded(
@@ -85,10 +99,10 @@ def bsr_matmul_stacked(x, blocks, scales, row_idx, nnz, layer,
 
 def bsr_matmul_stacked_sharded(x, blocks, scales, row_idx, nnz, layer, mesh,
                                bm: int = 128, interpret: bool | None = None,
-                               axis: str = cim_bsr_matmul.MACRO_AXIS):
+                               axis: str | None = cim_bsr_matmul.MACRO_AXIS):
     """Macro-cluster tensor-parallel ``bsr_matmul_stacked``. Output columns
     are in device order - the caller un-permutes with the stack's per-layer
-    ``col_inv`` row."""
+    ``col_inv`` row. ``axis=None``: a stack replicated on the mesh."""
     if interpret is None:
         interpret = default_interpret()
     return cim_bsr_matmul.bsr_matmul_stacked_sharded(

@@ -38,8 +38,8 @@ def _kernel(x_ref, w_ref, s_ref, out_ref, acc_ref, *, nk: int):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def quant_matmul(x: jnp.ndarray, w: jnp.ndarray, scale: jnp.ndarray,
-                 bm: int = 128, bn: int = 128, bk: int = 128,
-                 interpret: bool = True) -> jnp.ndarray:
+                 bm: int = 128, bn: int = 128, bk: int = 128, *,
+                 interpret: bool) -> jnp.ndarray:
     m, k = x.shape
     k2, n = w.shape
     assert k == k2 and scale.shape == (n,)

@@ -45,7 +45,7 @@ def _kernel(a_ref, b_ref, c_ref, x_ref, y_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_intra_chunk(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray,
-                    x: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+                    x: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Batched intra-chunk SSD.
 
     a: (C, H, l); b, c: (C, l, N); x: (C, l, H, P)  ->  y: (C, l, H, P)

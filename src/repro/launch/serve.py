@@ -57,11 +57,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import registry
+from ..models import registry, transformer
 from ..obs import MetricsRegistry, Tracer
 from ..serve import (BatchConfig, BatchServer, Engine, Request, ServeConfig,
                      SpecConfig, deployed, stacked)
 from ..serve import spec as spec_mod
+from . import compile_cache
 
 
 def _legacy(args, cfg, params, fns=None):
@@ -290,10 +291,13 @@ def _report_artifact_autotune(cfg, meta):
               "no re-timing")
 
 
-def _serving_params(args, cfg, params, spec_cfg=None):
+def _serving_params(args, cfg, spec_cfg=None):
     """Build (or boot) the serving weights: the artifact flow runs the
     full search+quantize+prune+pack pipeline ONCE and later boots skip
-    straight to weights-on-device. Returns (target, draft-or-None,
+    straight to weights-on-device. A fresh build draws the weights from
+    ``--seed`` one layer at a time (``transformer.init_layerwise``) and
+    packs each layer as it is drawn, so the dense model never sits on the
+    device whole. Returns (target, draft-or-None,
     spec_cfg); with ``spec_cfg`` the draft tier rides the same artifact
     (two-tier) - an existing single-tier artifact is upgraded in place
     (draft re-packed from the STORED target packing, then re-saved with
@@ -354,11 +358,18 @@ def _serving_params(args, cfg, params, spec_cfg=None):
     if args.compressed and args.autotune > 0 and tile is None:
         at_result, at_cache = _run_autotune(args, cfg)
         tile = at_result.best_tile
+    params, layer = transformer.init_layerwise(cfg,
+                                               jax.random.PRNGKey(args.seed))
+    t0 = time.perf_counter()
     sp = (deployed.compress(cfg, params, target_sparsity=args.target_sparsity,
                             schedule=(None if tile else
                                       deployed.default_schedule(cfg)),
-                            tile=tile, uniform=at_result is not None)
-          if args.compressed else deployed.from_params(cfg, params))
+                            tile=tile, uniform=at_result is not None,
+                            layer=layer)
+          if args.compressed
+          else deployed.from_params(cfg, params, layer=layer))
+    print(f"pack: {time.perf_counter() - t0:.1f}s, tiles "
+          f"{deployed.packed_tiles(sp)}")
     if spec_cfg is not None and spec_cfg.draft == "reprune":
         draft = spec_mod.draft_serving(cfg, sp, spec_cfg.draft_sparsity,
                                        tile=tile)
@@ -375,12 +386,12 @@ def _serving_params(args, cfg, params, spec_cfg=None):
     return sp, draft, spec_cfg
 
 
-def _batch(args, cfg, params):
+def _batch(args, cfg):
     mesh = _parse_mesh(args.mesh)
     calibration = _load_calibration(args) if args.spec else None
     spec_cfg = _parse_spec(args.spec, cfg, args.target_sparsity,
                            calibration=calibration)
-    sp, draft, spec_cfg = _serving_params(args, cfg, params, spec_cfg)
+    sp, draft, spec_cfg = _serving_params(args, cfg, spec_cfg)
     if args.compressed:
         print("compression:", json.dumps(sp.report()))
     if spec_cfg is not None and spec_cfg.draft == "layerskip":
@@ -394,8 +405,7 @@ def _batch(args, cfg, params):
         sp = deployed.shard(sp, mesh)
         if draft is not None:
             draft = deployed.shard(draft, mesh)
-        n_sharded = sum(1 for dw in sp.deployed().values()
-                        if dw.mesh is not None)
+        n_sharded = sum(dw.sharded for dw in sp.deployed().values())
         print(f"macro mesh: {mesh.shape} - {n_sharded} projections "
               "column-sharded (rest replicated)")
     bcfg = BatchConfig(n_slots=args.slots, block_size=args.block_size,
@@ -501,6 +511,18 @@ def _batch(args, cfg, params):
         print(f"metrics: snapshot -> {args.metrics_out}")
     if args.profile:
         print(f"profile: jax.profiler trace -> {args.profile}")
+    stats = jax.devices()[0].memory_stats()
+    if stats and "peak_bytes_in_use" in stats:
+        print(f"device: {jax.devices()[0].device_kind} peak_bytes_in_use="
+              f"{stats['peak_bytes_in_use']}")
+
+
+def _config(args, arch):
+    """The registry config ``--smoke`` / ``--dtype`` select."""
+    dtype = args.dtype or ("float32" if args.smoke else "")
+    overrides = {"dtype": dtype} if dtype else {}
+    return (registry.get_smoke_config(arch, **overrides) if args.smoke
+            else registry.get_config(arch, **overrides))
 
 
 def _tenant_cfg_sp(entry, args):
@@ -512,8 +534,7 @@ def _tenant_cfg_sp(entry, args):
         raise SystemExit(
             f"tenant {entry.get('name')!r}: no 'arch' in tenants.json and "
             "no --arch fallback")
-    cfg = (registry.get_smoke_config(arch, dtype=args.dtype) if args.smoke
-           else registry.get_config(arch, dtype=args.dtype))
+    cfg = _config(args, arch)
     tile = _parse_tile(entry.get("tile", ""))
     if entry.get("artifact"):
         sp, _, _ = deployed.load_artifact_tiers(
@@ -732,10 +753,13 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--dtype", default="float32")
+    ap.add_argument("--dtype", default="",
+                    help="parameter dtype; default float32 with --smoke, "
+                    "else the published config's own (bfloat16)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     if args.gateway:
         _gateway(args)
         return
@@ -743,10 +767,7 @@ def main(argv=None):
         ap.error("--arch is required (unless --gateway names per-tenant "
                  "arches)")
 
-    cfg = (registry.get_smoke_config(args.arch, dtype=args.dtype) if args.smoke
-           else registry.get_config(args.arch, dtype=args.dtype))
-    fns = registry.model_fns(cfg)
-    params = fns.init_params(cfg, jax.random.PRNGKey(args.seed))
+    cfg = _config(args, args.arch)
 
     use_legacy = args.engine == "legacy"
     if not use_legacy and cfg.family not in ("dense", "moe"):
@@ -756,19 +777,22 @@ def main(argv=None):
 
     if use_legacy:
         if args.compressed:
-            sp, _, _ = _serving_params(args, cfg, params)
+            sp, _, _ = _serving_params(args, cfg)
             print("compression:", json.dumps(sp.report()))
             if args.runtime == "scan":
                 _legacy(args, cfg, stacked.stack(sp),
                         fns=stacked.model_fns(cfg))
             else:
-                _legacy(args, cfg, sp, fns=deployed.model_fns(cfg))
+                _legacy(args, cfg, jax.device_put(sp),
+                        fns=deployed.model_fns(cfg))
         else:
             # uncompressed legacy serving already runs the registry's
             # scan-over-layers forward - both --runtime values coincide
+            params = registry.model_fns(cfg).init_params(
+                cfg, jax.random.PRNGKey(args.seed))
             _legacy(args, cfg, params)
     else:
-        _batch(args, cfg, params)
+        _batch(args, cfg)
 
 
 if __name__ == "__main__":

@@ -77,9 +77,8 @@ def _stack(layer_fn, keys):
     return jax.vmap(layer_fn)(keys)
 
 
-def init_params(cfg: ModelConfig, key) -> dict:
+def _init_globals(cfg: ModelConfig, keys) -> dict:
     dtype = cfg.param_dtype
-    keys = jax.random.split(key, 8)
     d = cfg.d_model
     params = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_eff, d), dtype) * 0.02,
@@ -87,6 +86,32 @@ def init_params(cfg: ModelConfig, key) -> dict:
     }
     if not cfg.tie_embeddings:
         params["head"] = jax.random.normal(keys[1], (d, cfg.vocab_eff), dtype) * 0.02
+    if cfg.family == "vlm":
+        params["mm_proj"] = jax.random.normal(keys[5], (d, d), dtype) * (1.0 / d**0.5)
+    return params
+
+
+def init_layerwise(cfg: ModelConfig, key):
+    """``init_params`` for the dense / moe / vlm families, one layer at a
+    time: returns (params without ``"layers"``, ``layer``) where
+    ``layer(i)`` builds layer i's dict - the values of
+    ``init_params(cfg, key)["layers"][i]`` - on demand. A caller that
+    packs or serves layer by layer never holds the whole layer stack."""
+    if cfg.family not in ("dense", "moe", "vlm"):
+        raise ValueError(f"init_layerwise: no per-layer form for {cfg.family!r}")
+    keys = jax.random.split(key, 8)
+    lkeys = jax.random.split(keys[2], cfg.n_layers)
+    # eager like init_params: a jit may fold the scale constants and
+    # round differently in the last place
+    return (_init_globals(cfg, keys),
+            lambda i: _attn_layer_init(lkeys[i], cfg, cfg.param_dtype))
+
+
+def init_params(cfg: ModelConfig, key) -> dict:
+    dtype = cfg.param_dtype
+    keys = jax.random.split(key, 8)
+    d = cfg.d_model
+    params = _init_globals(cfg, keys)
 
     if cfg.family in ("dense", "moe", "vlm"):
         lkeys = jax.random.split(keys[2], cfg.n_layers)
@@ -118,9 +143,6 @@ def init_params(cfg: ModelConfig, key) -> dict:
         params["attn_gate"] = jnp.ones((n_super,), jnp.float32)
     else:
         raise ValueError(cfg.family)
-
-    if cfg.family == "vlm":
-        params["mm_proj"] = jax.random.normal(keys[5], (d, d), dtype) * (1.0 / d**0.5)
     return params
 
 

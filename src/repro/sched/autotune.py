@@ -277,7 +277,8 @@ def autotune(cfg, top_n: int = 3, *, target_sparsity: float = 0.6,
     if search is None:
         graph = lm_graph(cfg, seq_len=seq_len, sparsity_gs=target_sparsity)
         search = search_mapping(graph, hw, cfg.w_bits, cfg.a_bits,
-                                groups=groups, alphas=alphas, uniform=True)
+                                groups=groups, alphas=alphas, uniform=True,
+                                quantum=ops.tile_quantum())
     sim_tile = search.best.candidate.tile
     if cache is not None:
         hit = cache.get(key)

@@ -175,7 +175,7 @@ def verify_layer(x, w, sched: LayerSchedule, cim: CIMConfig,
     max abs error (raises if above tolerance)."""
     d_in, d_out = int(w.shape[-2]), int(w.shape[-1])
     bk, bn = _deploy_tile(sched, d_in, d_out)
-    got = execute_layer(x, w, sched, cim, target_sparsity, interpret=True)
+    got = execute_layer(x, w, sched, cim, target_sparsity)
     want = D.reference_matmul(x, w, cim, target_sparsity=target_sparsity,
                               bk=bk, bn=bn)
     err = float(jnp.max(jnp.abs(got - want)))

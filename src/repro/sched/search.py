@@ -96,7 +96,8 @@ def search_mapping(graph: LayerGraph, hw: HardwareConfig = DEFAULT_HW,
                    alphas: Sequence[int] = (8, 16, 32),
                    pipeline: bool = True,
                    budget: Optional[int] = None,
-                   uniform: bool = False) -> SearchResult:
+                   uniform: bool = False,
+                   quantum: int = 1) -> SearchResult:
     """Grid search over tile shapes; ``budget`` caps simulated candidates
     (the default mapping never counts against it).
 
@@ -106,6 +107,11 @@ def search_mapping(graph: LayerGraph, hw: HardwareConfig = DEFAULT_HW,
     network deploys with (``stack_deployed`` requires it). The default
     mapping is kept only if itself feasible; with no feasible candidate at
     all the search fails loudly rather than silently clipping per layer.
+
+    ``quantum`` drops every candidate (the default mapping included) whose
+    group or alpha is not a multiple of it: a serving search passes the
+    BSR kernel's tile quantum (``kernels.ops.tile_quantum``), so it never
+    picks a tile the kernel cannot run on this backend.
     """
     cands = [default_candidate(hw, pipeline)]
     for g in groups:
@@ -113,7 +119,13 @@ def search_mapping(graph: LayerGraph, hw: HardwareConfig = DEFAULT_HW,
             c = MappingCandidate(g, a, pipeline)
             if c not in cands:
                 cands.append(c)
-    has_default = True
+    cands = [c for c in cands if c.group % quantum == 0
+             and c.alpha % quantum == 0]
+    if not cands:
+        raise ValueError(
+            f"search_mapping: no candidate tile is a multiple of {quantum} "
+            "- offer lane-aligned groups/alphas")
+    has_default = cands[0] == default_candidate(hw, pipeline)
     if uniform:
         cands = [c for c in cands
                  if tile_divides_graph(graph, c.group, c.alpha)]

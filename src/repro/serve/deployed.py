@@ -34,12 +34,13 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..core import deploy as D
+from ..kernels import ops
 from ..models import registry, transformer
 from ..models import layers as L
 from ..models.config import ModelConfig
@@ -104,13 +105,17 @@ def _check_family(cfg: ModelConfig) -> None:
             "adaptation yet)")
 
 
-def from_params(cfg: ModelConfig, params: dict) -> ServingParams:
-    """Unstack registry params (stacked (L, ...) leaves) into per-layer
-    dicts, without compressing anything."""
-    _check_family(cfg)
-    layers = [jax.tree.map(lambda a: a[i], params["layers"])
-              for i in range(cfg.n_layers)]
-    head = params.get("head")
+def _layer_source(params: dict, layer: Optional[Callable[[int], dict]]):
+    """``layer(i)`` -> layer i's param dict: the caller's one-layer-at-a-
+    time source (``transformer.init_layerwise``), or a slice of the
+    stacked ``params["layers"]``."""
+    if layer is not None:
+        return layer
+    return lambda i: jax.tree.map(lambda a: a[i], params["layers"])
+
+
+def _serving(cfg: ModelConfig, params: dict, layers: List[dict],
+             head) -> ServingParams:
     return ServingParams(
         embed=params["embed"], final_ln=params["final_ln"], layers=layers,
         head=head, mm_proj=params.get("mm_proj"),
@@ -119,17 +124,33 @@ def from_params(cfg: ModelConfig, params: dict) -> ServingParams:
     )
 
 
+def from_params(cfg: ModelConfig, params: dict,
+                layer: Optional[Callable[[int], dict]] = None
+                ) -> ServingParams:
+    """Unstack registry params (stacked (L, ...) leaves) into per-layer
+    dicts, without compressing anything. ``layer`` (i -> layer i's dict)
+    replaces ``params["layers"]`` when given."""
+    _check_family(cfg)
+    source = _layer_source(params, layer)
+    return _serving(cfg, params, [source(i) for i in range(cfg.n_layers)],
+                    params.get("head"))
+
+
 def default_schedule(cfg: ModelConfig, seq_len: int = 128,
-                     groups=(16, 32, 64), alphas=(16, 32, 64),
+                     groups=None, alphas=None,
                      sparsity_gs: float = 0.6,
                      uniform: bool = False) -> NetworkSchedule:
     """Mapping search over the model's CIM projection graph: the returned
     schedule's per-layer (group, alpha) becomes the serving (bk, bn).
     ``uniform=True`` restricts the search to tiles that exactly divide every
-    projection (the stacked-deployment envelope)."""
+    projection (the stacked-deployment envelope). The grid defaults to the
+    tiles the BSR kernel runs on this backend (``kernels.ops.tile_sizes``),
+    and only tiles on its quantum are searched."""
     graph = lm_graph(cfg, seq_len=seq_len, sparsity_gs=sparsity_gs)
     result = search_mapping(graph, w_bits=cfg.w_bits, a_bits=cfg.a_bits,
-                            groups=groups, alphas=alphas, uniform=uniform)
+                            groups=groups or ops.tile_sizes(),
+                            alphas=alphas or ops.tile_sizes(),
+                            uniform=uniform, quantum=ops.tile_quantum())
     return schedule_from_search(graph, result, w_bits=cfg.w_bits,
                                 a_bits=cfg.a_bits)
 
@@ -163,7 +184,8 @@ def compress(cfg: ModelConfig, params: dict,
              target_sparsity: Optional[float] = None,
              schedule: Optional[NetworkSchedule] = None,
              tile: Optional[Tuple[int, int]] = None,
-             uniform: bool = False) -> ServingParams:
+             uniform: bool = False,
+             layer: Optional[Callable[[int], dict]] = None) -> ServingParams:
     """Pack every CIM-mapped 2-D projection for the BSR kernel.
 
     ``schedule`` (from ``sched.search`` over ``lm_graph(cfg)``) supplies the
@@ -178,8 +200,14 @@ def compress(cfg: ModelConfig, params: dict,
     clipped once to the largest shape that divides every projection, instead
     of per-projection clipping. This is the envelope contract
     ``serve.stacked`` / ``core.deploy.stack_deployed`` require.
+
+    Layers are packed one at a time and their dense weights dropped as soon
+    as they are packed; with ``layer`` (i -> layer i's dict, e.g. from
+    ``transformer.init_layerwise``) in place of ``params["layers"]`` the
+    whole dense layer stack never exists at once.
     """
-    sp = from_params(cfg, params)
+    _check_family(cfg)
+    source = _layer_source(params, layer)
     cim = cfg.cim
     tiles = {}
     if schedule is not None:
@@ -187,8 +215,12 @@ def compress(cfg: ModelConfig, params: dict,
     fallback = tile if tile is not None else (cfg.cim_alpha, cfg.cim_alpha)
     if uniform:
         g, a = schedule.uniform_tile if schedule is not None else fallback
-        net_tile = D.uniform_fit_tile(_projection_shapes(sp), g, a)
-        tiles, fallback = {}, net_tile
+        p0 = jax.eval_shape(source, 0)  # shapes only: nothing is drawn
+        shapes = [p0[proj].shape for proj in PROJECTIONS
+                  if getattr(p0.get(proj), "ndim", 0) == 2]
+        if params.get("head") is not None:
+            shapes.append(params["head"].shape)
+        tiles, fallback = {}, D.uniform_fit_tile(shapes, g, a)
 
     def pack(name: str, w) -> D.DeployedWeight:
         d_in, d_out = int(w.shape[-2]), int(w.shape[-1])
@@ -197,15 +229,18 @@ def compress(cfg: ModelConfig, params: dict,
         return D.deploy_weight(w, cim, bk=bk, bn=bn,
                                target_sparsity=target_sparsity)
 
-    for i, p in enumerate(sp.layers):
+    layers = []
+    for i in range(cfg.n_layers):
+        p = source(i)
         for proj in PROJECTIONS:
             w = p.get(proj)
             if w is None or getattr(w, "ndim", 0) != 2:
                 continue  # MoE expert stacks are (E, d, ff): leave dense
             p[proj] = pack(f"blk{i}_{proj}", w)
-    if sp.head is not None:
-        sp.head = pack("head", sp.head)
-    return sp
+        layers.append(p)
+    head = params.get("head")
+    return _serving(cfg, params, layers,
+                    pack("head", head) if head is not None else None)
 
 
 def shard(sp: ServingParams, mesh) -> ServingParams:
@@ -215,7 +250,8 @@ def shard(sp: ServingParams, mesh) -> ServingParams:
     the mesh's ``macro`` axis with the SAME LPT policy the scheduler uses to
     balance kernel-groups over macros (``sched.allocate.device_assignment``
     on the per-column surviving-block counts). Projections whose column
-    count does not divide the axis stay replicated - sharding never changes
+    count does not divide the axis stay replicated (``replicate_weight``:
+    every device runs the whole projection) - sharding never changes
     which blocks exist, so tokens are bit-identical to single-device
     serving. Dense leaves (embed, norms, MoE stacks) stay replicated.
     """
@@ -223,7 +259,8 @@ def shard(sp: ServingParams, mesh) -> ServingParams:
 
     def maybe(v):
         if isinstance(v, D.DeployedWeight):
-            return D.shard_weight(v, mesh, assign=device_assignment)
+            return D.replicate_weight(
+                D.shard_weight(v, mesh, assign=device_assignment), mesh)
         return v
 
     return ServingParams(

@@ -216,6 +216,7 @@ class BatchServer:
         # (CPU XLA can't alias freshly-transferred host arrays and only
         # warns, so donation is gated to real accelerator backends)
         donate = (1, 2) if jax.default_backend() != "cpu" else ()
+        jit, cfg_arg = stacked.serving_jit, ("cfg",)
         self.spec = None
         if engine == "spec":
             self.spec = spec if spec is not None else spec_mod.SpecConfig()
@@ -235,11 +236,9 @@ class BatchServer:
                     "accept rule matches draft tokens against the target's "
                     "argmaxes, which is exact only for greedy decode")
             self._params = spec_mod.SpecParams.build(sp, draft)
-            self._prefill = jax.jit(stacked.prefill_last,
-                                    static_argnames=("cfg",))
-            self._verify = jax.jit(stacked.verify_step,
-                                   static_argnames=("cfg",),
-                                   donate_argnums=donate)
+            self._prefill = jit(stacked.prefill_last, static_argnames=cfg_arg)
+            self._verify = jit(stacked.verify_step, static_argnames=cfg_arg,
+                               donate_argnums=donate)
             if self.spec.draft == "layerskip":
                 # sublayer masks, ranked by the packed envelope's own nnz:
                 # sublayers the compression already killed are dropped first
@@ -250,33 +249,30 @@ class BatchServer:
                 self.spec_masks = (attn_on, mlp_on)
                 self._attn_on = jnp.asarray(attn_on, jnp.float32)
                 self._mlp_on = jnp.asarray(mlp_on, jnp.float32)
-                self._draft_propose = jax.jit(
+                self._draft_propose = jit(
                     spec_mod.draft_propose_layerskip,
                     static_argnames=("cfg", "k"), donate_argnums=donate)
             else:
                 self.spec_masks = None
-                self._draft_propose = jax.jit(spec_mod.draft_propose,
-                                              static_argnames=("cfg", "k"),
-                                              donate_argnums=donate)
+                self._draft_propose = jit(spec_mod.draft_propose,
+                                          static_argnames=("cfg", "k"),
+                                          donate_argnums=donate)
         elif engine == "scan":
             self._params = stacked.stack(sp)
-            self._prefill = jax.jit(stacked.prefill_last,
-                                    static_argnames=("cfg",))
-            self._decode = jax.jit(stacked.decode_step_paged,
-                                   static_argnames=("cfg",),
-                                   donate_argnums=donate)
+            self._prefill = jit(stacked.prefill_last, static_argnames=cfg_arg)
+            self._decode = jit(stacked.decode_step_paged,
+                               static_argnames=cfg_arg, donate_argnums=donate)
             # multi-token pass for the prefix-cache suffix prefill
-            self._verify = jax.jit(stacked.verify_step,
-                                   static_argnames=("cfg",),
-                                   donate_argnums=donate)
+            self._verify = jit(stacked.verify_step, static_argnames=cfg_arg,
+                               donate_argnums=donate)
         else:
-            self._params = sp
-            self._prefill = jax.jit(deployed.prefill_last,
-                                    static_argnames=("cfg",))
-            self._decode = jax.jit(deployed.decode_step_paged,
-                                   static_argnames=("cfg",))
-            self._verify = jax.jit(deployed.verify_step,
-                                   static_argnames=("cfg",))
+            # packings live on the host until a runtime places them: the
+            # loop runtime runs the per-layer form, so it goes on the device
+            self._params = jax.device_put(sp)
+            self._prefill = jit(deployed.prefill_last, static_argnames=cfg_arg)
+            self._decode = jit(deployed.decode_step_paged,
+                               static_argnames=cfg_arg)
+            self._verify = jit(deployed.verify_step, static_argnames=cfg_arg)
         # speculative lookahead: a verify writes KV up to pos+k, so
         # worst-case reservation must cover k extra positions per slot
         self._lookahead = self.spec.k if self.spec is not None else 0

@@ -70,6 +70,21 @@ class StackedParams:
         return int(next(iter(self.packed.values())).n_layers)
 
 
+# By default XLA may keep bf16 intermediates at higher precision wherever
+# its fusion choices allow, so the same step compiled for one device and
+# for a macro mesh (different fusions) rounds differently: on a TPU v5e the
+# greedy tokens of full-width yi-6b diverged. Serving steps compile with
+# exactly the precision the program states - every op rounds to its dtype.
+SERVING_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
+
+def serving_jit(fn, **kw):
+    """``jax.jit`` for a serving step, with ``SERVING_COMPILER_OPTIONS``:
+    the step's bits do not depend on how the compiler fuses it, so they
+    are the same on one device and sharded."""
+    return jax.jit(fn, compiler_options=SERVING_COMPILER_OPTIONS, **kw)
+
+
 jax.tree_util.register_pytree_node(
     StackedParams,
     lambda sp: ((sp.embed, sp.final_ln, sp.dense, sp.packed, sp.head,
@@ -107,9 +122,11 @@ def stack(sp: DP.ServingParams) -> StackedParams:
             raise ValueError(
                 f"stack: projection {k!r} is packed in {n_packed}/{len(vs)} "
                 "layers - compress() packs all layers or none")
-    return StackedParams(embed=sp.embed, final_ln=sp.final_ln, dense=dense,
-                         packed=packed, head=sp.head, mm_proj=sp.mm_proj,
-                         head_t=sp.head_t)
+    # one device copy of what the scan runs: packed leaves still on the
+    # host (the head's packing) move once here, not on every call
+    return jax.device_put(StackedParams(
+        embed=sp.embed, final_ln=sp.final_ln, dense=dense, packed=packed,
+        head=sp.head, mm_proj=sp.mm_proj, head_t=sp.head_t))
 
 
 # StackedParams exposes the same head/head_t/embed fields as ServingParams,

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
-
 from ..models.config import ModelConfig
 from . import optimizer as opt
 from .trainer import TrainConfig, make_loss_fn
@@ -74,7 +72,7 @@ def make_compressed_dp_train_step(model_cfg: ModelConfig, tcfg: TrainConfig,
 
     state_spec = {"params": P(), "opt": P(), "err": P(), "step": P()}
     # batch sharded over the DP axis; metrics replicated
-    step = shard_map(
+    step = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(state_spec, P(axis)),
         out_specs=(state_spec, P()),

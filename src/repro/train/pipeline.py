@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
-
 
 def pipeline_apply(stage_fn: Callable, n_stages: int, mesh: Mesh,
                    axis: str = "pipe") -> Callable:
@@ -59,7 +57,7 @@ def pipeline_apply(stage_fn: Callable, n_stages: int, mesh: Mesh,
         outs = jax.lax.psum(outs, axis)
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -220,3 +220,84 @@ def test_uniform_fit_tile():
     assert deploy.uniform_fit_tile(shapes, 48, 48) == (32, 32)
     assert deploy.uniform_fit_tile([(60, 90)], 16, 16) == (15, 15)
     assert deploy.uniform_fit_tile([], 16, 16) == (16, 16)
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "phi3.5-moe-42b-a6.6b",
+                                  "llava-next-34b"])
+def test_init_layerwise_matches_init_params(arch):
+    """The one-layer-at-a-time init draws exactly init_params' values."""
+    from repro.models import transformer
+
+    cfg = registry.get_smoke_config(arch, dtype="float32")
+    key = jax.random.PRNGKey(3)
+    full = transformer.init_params(cfg, key)
+    glob, layer = transformer.init_layerwise(cfg, key)
+    assert set(glob) == set(full) - {"layers"}
+    for k, v in glob.items():
+        np.testing.assert_array_equal(np.asarray(v), np.asarray(full[k]))
+    for i in range(cfg.n_layers):
+        got = layer(i)
+        assert set(got) == set(full["layers"])
+        for k, v in got.items():
+            np.testing.assert_array_equal(
+                np.asarray(v), np.asarray(full["layers"][k][i]),
+                err_msg=f"{k} layer {i}")
+
+
+def test_compress_from_layer_source_matches_stacked_params():
+    """Packing layer by layer from init_layerwise gives the packing of the
+    stacked params, leaf for leaf."""
+    from repro.models import transformer
+    from repro.serve import deployed as DP
+
+    cfg = registry.get_smoke_config("yi-6b", dtype="float32",
+                                    cim_mode="qat")
+    key = jax.random.PRNGKey(0)
+    want = DP.compress(cfg, transformer.init_params(cfg, key),
+                       target_sparsity=0.5, tile=(16, 16), uniform=True)
+    glob, layer = transformer.init_layerwise(cfg, key)
+    got = DP.compress(cfg, glob, target_sparsity=0.5, tile=(16, 16),
+                      uniform=True, layer=layer)
+    a, b = jax.tree.leaves(want), jax.tree.leaves(got)
+    assert len(a) == len(b)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    # packings stay on the host until a runtime places them
+    assert isinstance(got.layers[0]["wq"].packed[0]["blocks"], np.ndarray)
+
+
+def test_compressed_matmul_keeps_activation_dtype():
+    """bf16 activations come back bf16 even though the kernel runs on the
+    f32-quantized activation (the scan carry's dtype must not change)."""
+    dw = deploy.deploy_weight(
+        np.random.default_rng(0).standard_normal((64, 32)).astype(np.float32),
+        _cim(w_bits=8), bk=16, bn=16, target_sparsity=0.5)
+    x = jnp.ones((3, 64), jnp.bfloat16)
+    assert deploy.deployed_matmul(x, dw, a_bits=8).dtype == jnp.bfloat16
+    sw = deploy.stack_deployed([dw, dw])
+    assert deploy.stacked_matmul(x, sw, 1, a_bits=8).dtype == jnp.bfloat16
+
+
+def test_tpu_tile_rule(monkeypatch):
+    """With the compiled kernel (TPU), only 128-multiple tiles fit, and a
+    tile that cannot fit is refused by name."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    assert ops.tile_quantum() == 128
+    assert ops.tile_sizes() == K.COMPILED_TILES
+    # 11008 = 256 * 43: the largest 128-multiple edge <= 512 is 256
+    assert deploy.fit_tile(4096, 11008, 512, 512) == (512, 256)
+    assert deploy.fit_tile(11008, 4096, 512, 512) == (256, 512)
+    yi = [(4096, 4096), (4096, 512), (4096, 11008), (11008, 4096),
+          (4096, 64000)]
+    assert deploy.uniform_fit_tile(yi, 512, 512) == (256, 256)
+    with pytest.raises(ValueError, match="16x16"):
+        deploy.fit_tile(4096, 4096, 16, 16)
+    with pytest.raises(ValueError, match="64x128"):
+        K.bsr_matmul(jnp.ones((8, 64)), jnp.ones((1, 1, 64, 128), jnp.int8),
+                     jnp.ones((1, 1)), jnp.zeros((1, 1), jnp.int32),
+                     jnp.ones((1,), jnp.int32), interpret=False)
+    # off-TPU every tile runs (interpret mode)
+    monkeypatch.setattr(ops, "default_interpret", lambda: True)
+    assert deploy.fit_tile(64, 48, 16, 32) == (16, 24)

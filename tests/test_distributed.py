@@ -86,7 +86,10 @@ def sequential(w, xs):
         return y
     return jax.vmap(one)(xs)
 
-mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+# shard_map pipeline called under a plain jit: the mesh's axes must be
+# Auto (jax.make_mesh defaults to Explicit axes)
+mesh = jax.make_mesh((4, 2), ("pipe", "data"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 staged = stack_stages(w, NS)
 pipe_fn = pipeline_apply(scan_stage(layer_fn), NS, mesh, "pipe")
 

@@ -536,18 +536,30 @@ def test_layerskip_masks_rank_and_floor():
     assert a_on[3] == 1 and m_on[2] == 1  # most important survive
 
 
+def _dead_sublayer_packing(cfg, params):
+    """A compressed packing with known dead sublayers: wk/wv are zero in
+    every layer (nothing survives pruning, so every attention outputs 0)
+    and layer 0's w_down is zero (its MLP outputs 0)."""
+    layers = dict(params["layers"])
+    layers["wk"] = jnp.zeros_like(layers["wk"])
+    layers["wv"] = jnp.zeros_like(layers["wv"])
+    layers["w_down"] = layers["w_down"].at[0].set(0.0)
+    return DP.compress(cfg, {**params, "layers": layers},
+                       target_sparsity=0.6,
+                       schedule=DP.default_schedule(cfg))
+
+
 def test_sublayer_importance_detects_dead_sublayers(qat_model):
-    """On the aggressively-compressed smoke packing the nnz ranking must
-    score pruning-killed sublayers exactly 0 (skipping them is free)."""
+    """The nnz ranking must score pruning-killed sublayers exactly 0
+    (skipping them is free)."""
     cfg, params = qat_model
-    sp = DP.compress(cfg, params, target_sparsity=0.6,
-                     schedule=DP.default_schedule(cfg))
-    sxp = ST.stack(sp)
+    sxp = ST.stack(_dead_sublayer_packing(cfg, params))
     attn, mlp = SP.sublayer_importance(sxp)
     assert attn.shape == (cfg.n_layers,) and mlp.shape == (cfg.n_layers,)
     assert np.all(attn >= 0) and np.all(mlp >= 0)
-    # this packing's wk/wv lose every block -> both attentions are dead
+    # wk/wv lost every block -> every attention is dead
     assert np.all(attn == 0)
+    assert mlp[0] == 0 and np.all(mlp[1:] > 0)
     # masks at keep=0.5 must then shed ONLY dead/cheapest units
     a_on, m_on = SP.layerskip_masks(cfg.n_layers, 0.5,
                                     importance=(attn, mlp))
@@ -563,8 +575,7 @@ def test_layerskip_spec_matches_scan(qat_model):
     """Greedy bit-exactness for the layerskip family: no draft packing,
     the draft runs a sublayer subset of the TARGET envelope."""
     cfg, params = qat_model
-    sp = DP.compress(cfg, params, target_sparsity=0.6,
-                     schedule=DP.default_schedule(cfg))
+    sp = _dead_sublayer_packing(cfg, params)
     bcfg = BatchConfig(**_BCFG)
     want = BatchServer(cfg, sp, ServeConfig(), bcfg, engine="scan"
                        ).run(_trace(cfg))
@@ -675,8 +686,7 @@ def test_adaptive_k_collapses_in_server(qat_model):
     live compute is elsewhere) must drive per-slot k down; greedy tokens
     stay bit-identical through every k trajectory."""
     cfg, params = qat_model
-    sp = DP.compress(cfg, params, target_sparsity=0.6,
-                     schedule=DP.default_schedule(cfg))
+    sp = _dead_sublayer_packing(cfg, params)
     bcfg = BatchConfig(**_BCFG)
     want = BatchServer(cfg, sp, ServeConfig(), bcfg, engine="scan"
                        ).run(_trace(cfg))

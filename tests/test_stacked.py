@@ -233,6 +233,26 @@ def test_deployed_matmul_same_result_across_buckets():
     np.testing.assert_array_equal(full[:1], one)
 
 
+def test_serving_jit_rounds_every_op_to_its_dtype():
+    """A serving step's bf16 math rounds exactly as the program states,
+    op by op - not at whatever precision the compiler's fusions keep (a
+    plain jax.jit of this function differs from the op-by-op result on
+    the CPU) - so the bits cannot depend on how a mesh changes fusion."""
+    def f(x, w):
+        h = x @ w
+        h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True)
+                              + jnp.bfloat16(1e-5))
+        return jax.nn.softmax(h, -1) * h + jax.nn.silu(h)
+
+    kx, kw = jax.random.split(jax.random.PRNGKey(0))
+    x = jax.random.normal(kx, (64, 256), jnp.bfloat16)
+    w = jax.random.normal(kw, (256, 512), jnp.bfloat16)
+    with jax.disable_jit():
+        op_by_op = np.asarray(f(x, w), np.float32)
+    got = np.asarray(ST.serving_jit(f)(x, w), np.float32)
+    np.testing.assert_array_equal(got, op_by_op)
+
+
 # ---------------------------------------------------------------------------
 # Scan runtime == loop runtime (tokens bit-exact)
 # ---------------------------------------------------------------------------
