@@ -142,7 +142,6 @@ def _serve_timed(cfg, sp, continuous: bool, trace_fn, repeats: int = 2,
     # warmup spans/samples are compile noise, not serving cost
     srv.metrics.clear()
     srv.tracer.clear()
-    srv.timer.clear()
     best = None
     for _ in range(repeats):
         rep = srv.run(trace_fn())

@@ -163,6 +163,8 @@ def bsr_matmul_stacked(x: jnp.ndarray, blocks: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((x.shape[0], go * bn), acc_dtype),
         interpret=interpret,
+        # the name a device trace shows the kernel's calls under
+        name="bsr_matmul_stacked",
     )(layer, pick(row_idx).astype(jnp.int32), pick(nnz).astype(jnp.int32),
       pick(scales).astype(jnp.float32), x, blocks)
     return out[:m]

@@ -418,8 +418,10 @@ def _batch(args, cfg):
              "spec": " (draft-k-verify speculative decode, greedy-exact)"
              }[engine])
     tracer = Tracer() if args.trace_out else None
+    # a registry records the phases, which then also land in the profile
     metrics = (MetricsRegistry()
-               if args.metrics_out or args.trace_out else None)
+               if args.metrics_out or args.trace_out or args.profile
+               else None)
     srv = BatchServer(cfg, sp, ServeConfig(temperature=args.temperature,
                                            seed=args.seed), bcfg,
                       continuous=(args.engine == "batch"), mesh=mesh,
@@ -450,7 +452,6 @@ def _batch(args, cfg):
         tracer.clear()
     if metrics is not None:
         metrics.clear()
-    srv.timer.clear()
     prof = (jax.profiler.trace(args.profile) if args.profile
             else contextlib.nullcontext())
     with prof:
@@ -702,11 +703,11 @@ def main(argv=None):
                     "counters) - open in Perfetto / chrome://tracing")
     ap.add_argument("--metrics-out", default="",
                     help="write the measured run's metrics snapshot "
-                    "(counters/gauges/phase histograms + fenced kernel "
-                    "dispatch table) as JSON")
+                    "(counters/gauges/phase histograms) as JSON")
     ap.add_argument("--profile", default="",
                     help="directory for a jax.profiler trace of the "
-                    "measured run (XLA-level, TensorBoard-loadable)")
+                    "measured run (XLA-level, TensorBoard-loadable), with "
+                    "the serving phases as host annotations")
     ap.add_argument("--shared-prefix", type=int, default=0, metavar="N",
                     help="serve a prefix-skewed trace instead of the mixed-"
                     "length one: 90%% of requests share an N-token system "

@@ -34,9 +34,11 @@ from .trace import (NullTracer, NULL_TRACER, Tracer,  # noqa: F401
 
 
 class _PhaseScope:
-    """Span + phase-latency histogram in one context manager."""
+    """Span + phase-latency histogram + profiler annotation in one context
+    manager."""
 
-    __slots__ = ("_tracer", "_metrics", "_name", "_args", "_span", "_t0")
+    __slots__ = ("_tracer", "_metrics", "_name", "_args", "_span", "_t0",
+                 "_annotation")
 
     def __init__(self, tracer, metrics_reg, name, args):
         self._tracer = tracer
@@ -45,6 +47,18 @@ class _PhaseScope:
         self._args = args
 
     def __enter__(self) -> "_PhaseScope":
+        # jax only when a phase records: the tracer stays stdlib-only
+        from jax import profiler
+
+        scalars = {k: v for k, v in self._args.items()
+                   if isinstance(v, (bool, int, float, str))}
+        if "step" in scalars:
+            step = scalars.pop("step")
+            self._annotation = profiler.StepTraceAnnotation(
+                self._name, step_num=step, **scalars)
+        else:
+            self._annotation = profiler.TraceAnnotation(self._name, **scalars)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         self._span = self._tracer.span(self._name, **self._args)
         self._span.__enter__()
@@ -54,12 +68,17 @@ class _PhaseScope:
         self._span.__exit__(*exc)
         self._metrics.histogram("serve_phase_s", phase=self._name).observe(
             time.perf_counter() - self._t0)
+        self._annotation.__exit__(*exc)
 
 
 def phase_scope(tracer, metrics_reg, name: str, **args):
-    """One instrumented phase: a tracer span plus a
-    ``serve_phase_s{phase=name}`` histogram observation. With both sinks
-    disabled this returns the shared no-op span - the zero-cost path."""
+    """One instrumented phase: a tracer span, a
+    ``serve_phase_s{phase=name}`` histogram observation, and a
+    ``jax.profiler.TraceAnnotation`` carrying the scalar args, so a
+    profiler session shows the phase on the device trace's own clock. A
+    phase with a ``step`` arg is a ``StepTraceAnnotation`` numbered by it.
+    With both sinks disabled this returns the shared no-op span - the
+    zero-cost path."""
     if not (tracer.recording or metrics_reg.recording):
         return trace._NULL_SPAN
     return _PhaseScope(tracer, metrics_reg, name, args)

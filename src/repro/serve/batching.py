@@ -270,6 +270,7 @@ class PagedKVCache:
         self.n_reused = 0
         self.n_cow = 0
         self.peak_blocks = 0
+        self.h2d_bytes = 0  # gathered view bytes copied host -> device
 
     # -- accounting ---------------------------------------------------------
 
@@ -295,6 +296,7 @@ class PagedKVCache:
             "reused_blocks": self.n_reused,
             "cow_copies": self.n_cow,
             "peak_blocks": self.peak_blocks,
+            "h2d_bytes": self.h2d_bytes,
             "kv_heads_sharded": self._view_sharding is not None,
         }
 
@@ -420,25 +422,44 @@ class PagedKVCache:
             tbl[r, :n] = t[:n]
         return tbl
 
+    @property
+    def position_bytes(self) -> int:
+        """Bytes of one cached position of one tensor (K or V), all layers."""
+        return (self.cfg.n_layers * self.cfg.n_kv_heads_eff * self.cfg.dh
+                * self.pool_k.itemsize)
+
+    def gather_host(self, tbl: np.ndarray, tensor: str,
+                    tier: int = 0) -> np.ndarray:
+        """The host stage of ``gather``: one tensor's ("k" or "v")
+        (L, B, n_view*block_size, KV, dh) view over the block table ``tbl``
+        (from ``view_tables``), in host memory."""
+        pool = self.pool_k if tensor == "k" else self.pool_v
+        g = pool[tier][tbl]  # (B, n_view, L, bs, KV, dh)
+        g = g.transpose(2, 0, 1, 3, 4, 5)
+        b, n_view = tbl.shape
+        return g.reshape(self.cfg.n_layers, b, n_view * self.block_size,
+                         self.cfg.n_kv_heads_eff, self.cfg.dh)
+
+    def place(self, view: np.ndarray) -> jnp.ndarray:
+        """The placement stage of ``gather``: copy a host view to the
+        device (sharded heads-wise on a macro mesh). The copy may still be
+        in flight when this returns."""
+        self.h2d_bytes += view.nbytes
+        out = jnp.asarray(view)
+        if self._view_sharding is not None:
+            out = jax.device_put(out, self._view_sharding)
+        return out
+
     def gather(self, n_view: int, tier: int = 0,
                slots: Optional[List[int]] = None
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """(L, B, n_view*block_size, KV, dh) contiguous K/V views; ``slots``
-        restricts B to those lanes (a cache-hit suffix pass gathers ONE)."""
+        """(L, B, n_view*block_size, KV, dh) contiguous K/V views on the
+        device; ``slots`` restricts B to those lanes (a cache-hit suffix
+        pass gathers ONE). K is placed before V is gathered, so V's host
+        work overlaps K's copy."""
         tbl = self.view_tables(n_view, slots)
-        L = self.cfg.n_layers
-        bs, kvh, dh = self.block_size, self.cfg.n_kv_heads_eff, self.cfg.dh
-
-        def _g(pool):
-            g = pool[tier][tbl]  # (B, n_view, L, bs, KV, dh)
-            g = g.transpose(2, 0, 1, 3, 4, 5)
-            out = jnp.asarray(
-                g.reshape(L, tbl.shape[0], n_view * bs, kvh, dh))
-            if self._view_sharding is not None:
-                out = jax.device_put(out, self._view_sharding)
-            return out
-
-        return _g(self.pool_k), _g(self.pool_v)
+        k = self.place(self.gather_host(tbl, "k", tier))
+        return k, self.place(self.gather_host(tbl, "v", tier))
 
     def write_coords(self, positions: List[Optional[int]]
                      ) -> Tuple[np.ndarray, np.ndarray]:

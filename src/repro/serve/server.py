@@ -30,7 +30,6 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..kernels.cim_bsr_matmul import MACRO_AXIS
-from ..kernels.timing import DispatchTimer
 from ..models.config import ModelConfig
 from ..obs import NULL_METRICS, NULL_TRACER, phase_scope
 from . import deployed, stacked
@@ -180,11 +179,12 @@ class BatchServer:
         does not implement.
 
         ``tracer`` / ``metrics`` (a :class:`repro.obs.Tracer` /
-        :class:`repro.obs.MetricsRegistry`) opt the loop into phase spans,
-        per-request lifecycle tracks, occupancy gauges and fenced kernel
-        dispatch timing. Default is the shared no-op singletons: every
-        phase boundary fence is gated on them, so the un-instrumented hot
-        path is byte-identical to an uninstrumented server."""
+        :class:`repro.obs.MetricsRegistry`) opt the loop into phase spans
+        (mirrored into the JAX profiler's trace), per-request lifecycle
+        tracks and occupancy gauges. Default is the shared no-op
+        singletons: every phase boundary fence is gated on them, so the
+        un-instrumented hot path is byte-identical to an uninstrumented
+        server."""
         if cfg.family == "vlm":
             raise NotImplementedError(
                 "BatchServer serves token-only requests; vlm prefill needs "
@@ -279,13 +279,6 @@ class BatchServer:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = metrics if metrics is not None else NULL_METRICS
         self._obs = bool(self.tracer.recording or self.metrics.recording)
-        # fenced per-(shape, tile, backend) kernel dispatch wall times;
-        # disabled with observability so tracing-off never serializes jax.
-        # The registry hookup mirrors each record into the
-        # kernel_dispatch_s histogram for the metrics snapshot.
-        self.timer = DispatchTimer(enabled=self._obs, metrics=self.metrics)
-        dep = sp.deployed()
-        self._tile = next(iter(dep.values())).tile if dep else None
 
     def _phase(self, name: str, **args):
         return phase_scope(self.tracer, self.metrics, name, **args)
@@ -462,12 +455,32 @@ class BatchServer:
     def _gather_views(self, slots: List[Optional[Slot]], kv: PagedKVCache,
                       active: List[int], lookahead: int, tier: int = 0):
         """Grow tables to cover this step's writes, then gather a bucketed
-        contiguous view of one KV tier."""
-        for i in active:
-            kv.ensure(i, slots[i].pos + 1 + lookahead)
-        nv = max(len(kv.tables[i]) for i in active)
-        nv = -(-nv // self.bcfg.view_bucket) * self.bcfg.view_bucket
-        return kv.gather(nv, tier=tier)
+        contiguous view of one KV tier: K on the host, K to the device, V
+        on the host, V to the device (V's host work overlaps K's copy).
+
+        Traced, each stage is a child span: ``kv.tables``, then per tensor
+        ``kv.gather`` and ``kv.h2d`` with the view's ``bytes`` and the
+        ``useful_bytes`` of the positions the active lanes read or write.
+        A traced ``kv.h2d`` fences its copy, so it holds the copy's time."""
+        with self._phase("kv.tables"):
+            for i in active:
+                kv.ensure(i, slots[i].pos + 1 + lookahead)
+            nv = max(len(kv.tables[i]) for i in active)
+            nv = -(-nv // self.bcfg.view_bucket) * self.bcfg.view_bucket
+            tbl = kv.view_tables(nv)
+        useful = (kv.position_bytes * sum(slots[i].pos + 1 + lookahead
+                                          for i in active)
+                  if self._obs else 0)
+        views = []
+        for tensor in ("k", "v"):
+            with self._phase("kv.gather", tensor=tensor):
+                host = kv.gather_host(tbl, tensor, tier)
+            with self._phase("kv.h2d", tensor=tensor, bytes=host.nbytes,
+                             useful_bytes=useful):
+                views.append(kv.place(host))
+                if self._obs:
+                    jax.block_until_ready(views[-1])
+        return tuple(views)
 
     def _decode_step(self, slots: List[Optional[Slot]], kv: PagedKVCache,
                      active: List[int], key) -> List[tuple]:
@@ -479,17 +492,15 @@ class BatchServer:
         observability off no extra fence runs and dispatch stays async."""
         with self._phase("step.gather", n_active=len(active)):
             views_k, views_v = self._gather_views(slots, kv, active, 0)
-            if self._obs:
-                jax.block_until_ready((views_k, views_v))
         pos = np.array([s.pos if s else 0 for s in slots], np.int32)
         toks = np.array([[s.next_token if s else 0] for s in slots],
                         np.int32)
         with self._phase("step.dispatch", engine=self.engine):
-            logits, k_new, v_new = self.timer.timed(
-                f"decode.{self.engine}",
-                (int(views_k.shape[1]), int(views_k.shape[2])), self._tile,
-                self._decode, self._params, views_k, views_v,
-                jnp.asarray(pos), jnp.asarray(toks), cfg=self.cfg)
+            logits, k_new, v_new = self._decode(
+                self._params, views_k, views_v, jnp.asarray(pos),
+                jnp.asarray(toks), cfg=self.cfg)
+            if self._obs:
+                jax.block_until_ready((logits, k_new, v_new))
         with self._phase("step.writeback"):
             pb, off = kv.write_coords([s.pos if s else None for s in slots])
             kv.write_token(pb, off, k_new, v_new)
@@ -680,12 +691,17 @@ class BatchServer:
                         time.sleep(min(wait, bcfg.idle_wait_s))
                 continue
 
+            # ``rids`` names the lanes this step gives a token: a request's
+            # gaps are its prefill's end, then the ends of these spans
             with self._phase("decode_step", step=n_steps,
-                             engine=self.engine, n_active=len(active)):
+                             engine=self.engine, n_active=len(active),
+                             rids=([slots[i].req.rid for i in active]
+                                   if self._obs else None)):
                 if self.spec is not None:
                     runs = self._spec_step(slots, kv, active)
                 else:
                     runs = self._decode_step(slots, kv, active, k_dec)
+            now = self._now()
             n_steps += 1
             if self._obs:
                 in_use = kv.blocks_in_use
@@ -696,7 +712,6 @@ class BatchServer:
                 self.metrics.counter("decode_steps").inc()
                 self.tracer.counter("serve", slots_active=len(active),
                                     kv_blocks_in_use=in_use)
-            now = self._now()
             for i, toks in runs:
                 s = slots[i]
                 for tok in toks:
@@ -714,9 +729,6 @@ class BatchServer:
         snap = None
         if self._obs:
             snap = self.metrics.snapshot() or None
-            disp = self.timer.summary()
-            if disp and snap is not None:
-                snap["kernel_dispatch"] = disp
         prefix = None
         if self._trie is not None:
             prefix = {k: (round(v, 4) if isinstance(v, float) else v)

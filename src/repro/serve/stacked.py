@@ -26,10 +26,8 @@ device or macro-sharded. ``tests/test_stacked.py`` enforces it.
 
 Timing a scan step is only meaningful at the dispatch boundary (the whole
 layer loop is ONE compiled call, so per-layer wall clocks don't exist):
-``BatchServer`` wraps the decode dispatch in
-``repro.kernels.timing.DispatchTimer`` - fenced with ``block_until_ready``,
-labeled ``decode.scan`` per (view shape, tile, backend) - when
-observability (``repro.obs``) is enabled.
+``BatchServer``'s ``step.dispatch`` span fences the decode dispatch with
+``block_until_ready`` when observability (``repro.obs``) is enabled.
 """
 from __future__ import annotations
 

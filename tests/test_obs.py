@@ -13,8 +13,8 @@ Contracts under test:
   * the per-phase cycle split in ``perf_model`` sums back to the exact
     ``_layer_cycles`` totals (the gap comparator's prediction side);
   * an instrumented ``BatchServer`` emits the step-phase spans, request
-    lifecycle tracks, queue-wait split and kernel dispatch table - and its
-    tokens are bit-identical to an un-instrumented server's.
+    lifecycle tracks and queue-wait split - and its tokens are
+    bit-identical to an un-instrumented server's.
 """
 import json
 import threading
@@ -505,10 +505,10 @@ def test_batchserver_instrumented_smoke(smoke_model, tmp_path):
     assert snap["counters"]["requests_finished"] == j["n_requests"]
     assert any(k.startswith("serve_phase_s{") for k in snap["histograms"])
     assert 0.0 <= snap["gauges"]["kv_utilization"] <= 1.0
-    disp = snap["kernel_dispatch"]
-    assert disp and all(r["name"] == "decode.loop" for r in disp)
-    # one fenced dispatch per decode step, grouped by view-shape bucket
-    assert sum(r["calls"] for r in disp) == j["n_decode_steps"]
+    # the decode dispatch is timed by its step.dispatch span alone
+    assert "kernel_dispatch" not in snap
+    assert not any(k.startswith("kernel_dispatch_s{")
+                   for k in snap["histograms"])
 
     # tokens identical to an un-instrumented server (observability is
     # read-only: it must never perturb the decode stream)

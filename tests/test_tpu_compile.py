@@ -10,6 +10,7 @@ and only the worker that runs this file loads the TPU library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +106,22 @@ def test_bsr_matmul_stacked_compiles_for_v5e(one_chip, proj, m, xdtype,
     _compiled_text(
         fn, (x, *_packing(d_in, d_out, tile, YI.n_layers, one_chip), layer),
         m)
+
+
+def test_kernel_keeps_its_name_in_the_compiled_program(one_chip):
+    """The device trace names the kernel's calls by the custom call's HLO
+    name, which the kernel sets itself, whatever function wraps it."""
+    d_in, d_out = PROJECTIONS["kv"]
+    x = _sds((8, d_in), jnp.float32, one_chip)
+
+    def some_wrapper(x, blocks, scales, row_idx, nnz):
+        return K.bsr_matmul(x, blocks, scales, row_idx, nnz, interpret=False)
+
+    text = _compiled_text(
+        some_wrapper, (x, *_packing(d_in, d_out, (256, 128), 0, one_chip)), 8)
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(", text)
+    assert calls and all(re.fullmatch(r"bsr_matmul_stacked(\.\d+)?", c)
+                         for c in calls), calls
 
 
 def test_unaligned_tile_is_refused_by_name():
