@@ -41,8 +41,9 @@ Block lifecycle contract (load-bearing for prefix sharing, see
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,81 @@ def kv_view_spec(cfg: ModelConfig, mesh: Mesh) -> Optional[P]:
     if n_dev > 1 and cfg.n_kv_heads_eff % n_dev == 0:
         return P(None, None, None, MACRO_AXIS, None)
     return None
+
+
+# -- the pool's device programs ----------------------------------------------
+#
+# Every read and write of the pool is one jitted program. Block ids, offsets,
+# the tier and the scrub value are traced operands, never static ones, so
+# each program compiles once per operand shape: the gather once per view
+# bucket, ``write_prefill`` once per padded prompt length, a decode step's
+# write, the copy-on-write copy and the scrub once each.
+
+
+def _take_view(pool, tbl, tier):
+    """(L, B, n_view*bs, KV, dh) view of ``pool[tier]`` over the block
+    table ``tbl`` (B, n_view)."""
+    g = pool[tier, tbl]  # (B, n_view, L, bs, KV, dh)
+    b, n_view, n_layers, bs, n_kv, dh = g.shape
+    return g.transpose(2, 0, 1, 3, 4, 5).reshape(n_layers, b, n_view * bs,
+                                                 n_kv, dh)
+
+
+def _put_rows(pool_k, pool_v, tier, pb, off, k, v):
+    """Entry j of ``k``/``v`` (L, n, KV, dh) into position ``off[j]`` of
+    block ``pb[j]`` of ``pool[tier]``."""
+    def put(pool, x):
+        return pool.at[tier, pb, :, off].set(
+            jnp.swapaxes(x, 0, 1).astype(pool.dtype))
+    return put(pool_k, k), put(pool_v, v)
+
+
+def _put_blocks(pool_k, pool_v, tier, ids, k, v):
+    """``k``/``v`` (L, S, KV, dh), cut into blocks, into blocks ``ids`` of
+    ``pool[tier]``; the last block is zero-padded past S."""
+    def put(pool, x):
+        n_layers, s, n_kv, dh = x.shape
+        n, bs = ids.shape[0], pool.shape[3]
+        x = jnp.pad(x, ((0, 0), (0, n * bs - s), (0, 0), (0, 0)))
+        blocks = x.reshape(n_layers, n, bs, n_kv, dh).swapaxes(0, 1)
+        return pool.at[tier, ids].set(blocks.astype(pool.dtype))
+    return put(pool_k, k), put(pool_v, v)
+
+
+def _copy_block(pool_k, pool_v, src, dst):
+    return (pool_k.at[:, dst].set(pool_k[:, src]),
+            pool_v.at[:, dst].set(pool_v[:, src]))
+
+
+def _fill_block(pool_k, pool_v, block, fill):
+    return (pool_k.at[:, block].set(fill.astype(pool_k.dtype)),
+            pool_v.at[:, block].set(fill.astype(pool_v.dtype)))
+
+
+class _PoolPrograms(NamedTuple):
+    take_view: object
+    put_rows: object
+    put_blocks: object
+    copy_block: object
+    fill_block: object
+
+
+@functools.lru_cache(maxsize=None)
+def _pool_programs(donate: bool, pool_sharding: Optional[NamedSharding],
+                   view_sharding: Optional[NamedSharding]) -> _PoolPrograms:
+    """The pool's programs for one placement, shared by every cache built
+    with it (a server builds a cache per ``run``), so a second run finds
+    each program compiled."""
+    view = {} if view_sharding is None else {"out_shardings": view_sharding}
+    write = {"donate_argnums": (0, 1) if donate else ()}
+    if pool_sharding is not None:
+        write["out_shardings"] = (pool_sharding, pool_sharding)
+    return _PoolPrograms(
+        take_view=jax.jit(_take_view, **view),
+        put_rows=jax.jit(_put_rows, **write),
+        put_blocks=jax.jit(_put_blocks, **write),
+        copy_block=jax.jit(_copy_block, **write),
+        fill_block=jax.jit(_fill_block, **write))
 
 
 @dataclasses.dataclass
@@ -215,10 +291,11 @@ class Slot:
 class PagedKVCache:
     """Block-pooled KV storage for the dense/moe/vlm attention cache.
 
-    pool_k / pool_v: (tiers, n_blocks, L, block_size, KV, dh). Per-slot
-    block tables map logical block i -> physical block id. ``gather``
-    produces the contiguous (L, B, Sv, KV, dh) view a decode step attends
-    over - sized by the deepest ACTIVE slot, not by the engine's max length.
+    pool_k / pool_v: (tiers, n_blocks, L, block_size, KV, dh) device
+    arrays. Per-slot block tables (host lists) map logical block i ->
+    physical block id. ``gather`` produces, on the device, the contiguous
+    (L, B, Sv, KV, dh) view a decode step attends over - sized by the
+    deepest ACTIVE slot, not by the engine's max length.
 
     ``tiers`` > 1 keeps SEVERAL KV pools behind ONE block layout: every
     tier shares the block tables, free list and accounting, so positions
@@ -239,21 +316,33 @@ class PagedKVCache:
         self.n_blocks = n_blocks
         self.block_size = block_size
         self.tiers = tiers
-        # macro-cluster serving: gathered views are sharded heads-wise over
-        # the mesh when KV heads divide it, so each device attends only its
-        # resident heads (and holds only 1/N of every block)
+        # macro-cluster serving: the pool and its gathered views are
+        # sharded heads-wise over the mesh when KV heads divide it, so each
+        # device holds and attends only its resident heads (1/N of every
+        # block); otherwise both are replicated over the mesh
         self.mesh = mesh
         spec = None if mesh is None else kv_view_spec(cfg, mesh)
         self._view_sharding = (None if spec is None
                                else NamedSharding(mesh, spec))
+        self._pool_sharding = self._table_sharding = None
+        if mesh is not None:
+            self._table_sharding = NamedSharding(mesh, P())
+            self._pool_sharding = (self._table_sharding if spec is None
+                                   else NamedSharding(mesh, P(None, *spec)))
         shape = (tiers, n_blocks, cfg.n_layers, block_size,
                  cfg.n_kv_heads_eff, cfg.dh)
-        # host numpy, written IN PLACE: a functional .at[].set would copy
-        # the whole pool per token, re-creating the max-len-copy cost the
-        # paged layout exists to avoid
-        np_dtype = np.dtype(dtype or cfg.param_dtype)
-        self.pool_k = np.zeros(shape, np_dtype)
-        self.pool_v = np.zeros(shape, np_dtype)
+        self.pool_k = jnp.zeros(shape, dtype or cfg.param_dtype,
+                                device=self._pool_sharding)
+        self.pool_v = jnp.zeros(shape, dtype or cfg.param_dtype,
+                                device=self._pool_sharding)
+        # every write is a functional update of the whole pool: donating
+        # the pool lets it land in place, so a write moves only the rows it
+        # touches. Donation is gated as the server gates it (CPU XLA
+        # only warns), and each write rebinds pool_k / pool_v to the result.
+        self._ops = _pool_programs(
+            jax.default_backend() != "cpu", self._pool_sharding,
+            self._view_sharding if spec is not None
+            else self._table_sharding)
         # LIFO free list => a freed block is the first one re-granted
         self._free: List[int] = list(range(1, n_blocks))
         self.tables: List[List[int]] = [[] for _ in range(n_slots)]
@@ -270,7 +359,7 @@ class PagedKVCache:
         self.n_reused = 0
         self.n_cow = 0
         self.peak_blocks = 0
-        self.h2d_bytes = 0  # gathered view bytes copied host -> device
+        self.h2d_bytes = 0  # block-table bytes gathers placed on the device
 
     # -- accounting ---------------------------------------------------------
 
@@ -335,9 +424,9 @@ class PagedKVCache:
 
     def _scrub(self, block: int) -> None:
         fill = (np.nan if self.debug_poison
-                and np.issubdtype(self.pool_k.dtype, np.floating) else 0)
-        self.pool_k[:, block] = fill
-        self.pool_v[:, block] = fill
+                and jnp.issubdtype(self.pool_k.dtype, jnp.floating) else 0.0)
+        self.pool_k, self.pool_v = self._ops.fill_block(
+            self.pool_k, self.pool_v, block, np.float32(fill))
 
     def adopt(self, slot: int, blocks: List[int]) -> None:
         """Append already-live shared blocks to ``slot``'s table (a
@@ -380,14 +469,26 @@ class PagedKVCache:
         if self.refcnt[pb] == 1:
             return pb
         nb = self._alloc()  # raises on exhaustion BEFORE any state moves
-        self.pool_k[:, nb] = self.pool_k[:, pb]
-        self.pool_v[:, nb] = self.pool_v[:, pb]
+        self.pool_k, self.pool_v = self._ops.copy_block(
+            self.pool_k, self.pool_v, pb, nb)
         self.tables[slot][block_idx] = nb
         self.release(pb)
         self.n_cow += 1
         return nb
 
     # -- data movement ------------------------------------------------------
+
+    def _local(self, x):
+        """A K/V operand where the pool's programs can take it. A single-
+        device pool is uncommitted (so are its views, as the decode
+        program's warm-up views are): an operand committed to a device
+        (a gateway's dedicated prefill device) comes by the host instead of
+        committing the pool. On a mesh, so does one committed elsewhere."""
+        if not getattr(x, "committed", False) or (
+                self._pool_sharding is not None and x.sharding.device_set
+                == self._pool_sharding.device_set):
+            return x
+        return np.asarray(x)
 
     def write_prefill(self, slot: int, k: jnp.ndarray, v: jnp.ndarray,
                       true_len: int, tier: int = 0, start: int = 0) -> None:
@@ -402,12 +503,15 @@ class PagedKVCache:
             raise ValueError(f"write_prefill start={start} must be a "
                              f"multiple of block_size={bs}")
         self.ensure(slot, start + true_len)
-        k, v = np.asarray(k), np.asarray(v)
         b0 = start // bs
+        # one id per block of the padded prefill; blocks past true_len
+        # write the scratch block
+        ids = np.zeros(self.blocks_for(k.shape[1]), np.int32)
         for i in range(self.blocks_for(true_len)):
-            pb = self._ensure_owned(slot, b0 + i)
-            self.pool_k[tier, pb] = k[:, i * bs:(i + 1) * bs]
-            self.pool_v[tier, pb] = v[:, i * bs:(i + 1) * bs]
+            ids[i] = self._ensure_owned(slot, b0 + i)
+        self.pool_k, self.pool_v = self._ops.put_blocks(
+            self.pool_k, self.pool_v, tier, ids, self._local(k),
+            self._local(v))
 
     def view_tables(self, n_view: int,
                     slots: Optional[List[int]] = None) -> np.ndarray:
@@ -422,44 +526,33 @@ class PagedKVCache:
             tbl[r, :n] = t[:n]
         return tbl
 
-    @property
-    def position_bytes(self) -> int:
-        """Bytes of one cached position of one tensor (K or V), all layers."""
-        return (self.cfg.n_layers * self.cfg.n_kv_heads_eff * self.cfg.dh
-                * self.pool_k.itemsize)
+    def place(self, tbl: np.ndarray) -> jnp.ndarray:
+        """The placement stage of ``gather``: copy a block table (from
+        ``view_tables``) to the device, replicated on a macro mesh. The
+        copy may still be in flight when this returns."""
+        self.h2d_bytes += tbl.nbytes
+        if self._table_sharding is not None:
+            return jax.device_put(tbl, self._table_sharding)
+        return jnp.asarray(tbl)
 
-    def gather_host(self, tbl: np.ndarray, tensor: str,
-                    tier: int = 0) -> np.ndarray:
-        """The host stage of ``gather``: one tensor's ("k" or "v")
-        (L, B, n_view*block_size, KV, dh) view over the block table ``tbl``
-        (from ``view_tables``), in host memory."""
+    def gather_view(self, tbl: jnp.ndarray, tensor: str,
+                    tier: int = 0) -> jnp.ndarray:
+        """The gather stage of ``gather``: one tensor's ("k" or "v")
+        (L, B, n_view*block_size, KV, dh) view over the placed block table
+        ``tbl``, taken on the device (sharded heads-wise on a macro mesh
+        whose devices divide the KV heads)."""
         pool = self.pool_k if tensor == "k" else self.pool_v
-        g = pool[tier][tbl]  # (B, n_view, L, bs, KV, dh)
-        g = g.transpose(2, 0, 1, 3, 4, 5)
-        b, n_view = tbl.shape
-        return g.reshape(self.cfg.n_layers, b, n_view * self.block_size,
-                         self.cfg.n_kv_heads_eff, self.cfg.dh)
-
-    def place(self, view: np.ndarray) -> jnp.ndarray:
-        """The placement stage of ``gather``: copy a host view to the
-        device (sharded heads-wise on a macro mesh). The copy may still be
-        in flight when this returns."""
-        self.h2d_bytes += view.nbytes
-        out = jnp.asarray(view)
-        if self._view_sharding is not None:
-            out = jax.device_put(out, self._view_sharding)
-        return out
+        return self._ops.take_view(pool, tbl, tier)
 
     def gather(self, n_view: int, tier: int = 0,
                slots: Optional[List[int]] = None
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(L, B, n_view*block_size, KV, dh) contiguous K/V views on the
         device; ``slots`` restricts B to those lanes (a cache-hit suffix
-        pass gathers ONE). K is placed before V is gathered, so V's host
-        work overlaps K's copy."""
-        tbl = self.view_tables(n_view, slots)
-        k = self.place(self.gather_host(tbl, "k", tier))
-        return k, self.place(self.gather_host(tbl, "v", tier))
+        pass gathers ONE). One block table is placed for both."""
+        tbl = self.place(self.view_tables(n_view, slots))
+        return self.gather_view(tbl, "k", tier), \
+            self.gather_view(tbl, "v", tier)
 
     def write_coords(self, positions: List[Optional[int]]
                      ) -> Tuple[np.ndarray, np.ndarray]:
@@ -478,12 +571,11 @@ class PagedKVCache:
     def write_token(self, pb: np.ndarray, off: np.ndarray,
                     k_new: jnp.ndarray, v_new: jnp.ndarray,
                     tier: int = 0) -> None:
-        """Write one decode step's K/V (L, B, KV, dh) into the pool (in
-        place - only the touched (block, offset) rows move)."""
-        kt = np.asarray(k_new).transpose(1, 0, 2, 3)  # (B, L, KV, dh)
-        vt = np.asarray(v_new).transpose(1, 0, 2, 3)
-        self.pool_k[tier][pb, :, off] = kt
-        self.pool_v[tier][pb, :, off] = vt
+        """Write one decode step's K/V (L, B, KV, dh) into the pool at the
+        (block, offset) of each lane (from ``write_coords``)."""
+        self.pool_k, self.pool_v = self._ops.put_rows(
+            self.pool_k, self.pool_v, tier, pb, off, self._local(k_new),
+            self._local(v_new))
 
     def write_run(self, slot: int, start: int, k_run: np.ndarray,
                   v_run: np.ndarray, tier: int = 0) -> None:
@@ -496,15 +588,13 @@ class PagedKVCache:
         reaching the pool (the gathered views the rejects were written
         into are throwaways)."""
         bs = self.block_size
-        k_run, v_run = np.asarray(k_run), np.asarray(v_run)
         n = k_run.shape[1]
         if n == 0:
             return
         for bi in range(start // bs, (start + n - 1) // bs + 1):
             self._ensure_owned(slot, bi)
         t = self.tables[slot]
-        for i in range(n):
-            pb = t[(start + i) // bs]
-            off = (start + i) % bs
-            self.pool_k[tier][pb, :, off] = k_run[:, i]
-            self.pool_v[tier][pb, :, off] = v_run[:, i]
+        at = np.arange(start, start + n)
+        pb = np.asarray([t[p // bs] for p in at], np.int32)
+        self.write_token(pb, (at % bs).astype(np.int32), k_run, v_run,
+                         tier=tier)

@@ -434,7 +434,6 @@ class BatchServer:
         vk, vv = kv.gather(nv, tier=0, slots=[i])
         logits, ks, vs = self._verify(target, vk, vv,
                                       pos, toks, cfg=self.cfg)
-        ks, vs = np.asarray(ks), np.asarray(vs)
         kv.write_run(i, m, ks[:, 0, :t], vs[:, 0, :t])
         if self.spec is not None and self._params.draft is not None:
             # reprune draft tier: same suffix pass over the tier-1 views, so
@@ -443,7 +442,6 @@ class BatchServer:
             _, kd, vd = self._verify(self._params.draft,
                                      dk, dv,
                                      pos, toks, cfg=self.cfg)
-            kd, vd = np.asarray(kd), np.asarray(vd)
             kv.write_run(i, m, kd[:, 0, :t], vd[:, 0, :t], tier=1)
         return logits[:, t - 1]
 
@@ -455,29 +453,31 @@ class BatchServer:
     def _gather_views(self, slots: List[Optional[Slot]], kv: PagedKVCache,
                       active: List[int], lookahead: int, tier: int = 0):
         """Grow tables to cover this step's writes, then gather a bucketed
-        contiguous view of one KV tier: K on the host, K to the device, V
-        on the host, V to the device (V's host work overlaps K's copy).
+        contiguous view of one KV tier on the device: the block table to
+        the device, then the K view and the V view taken from the pool.
 
-        Traced, each stage is a child span: ``kv.tables``, then per tensor
-        ``kv.gather`` and ``kv.h2d`` with the view's ``bytes`` and the
-        ``useful_bytes`` of the positions the active lanes read or write.
-        A traced ``kv.h2d`` fences its copy, so it holds the copy's time."""
+        Traced, each stage is a child span: ``kv.tables``, ``kv.h2d``
+        (``tensor="table"``) with the table's ``bytes`` and the
+        ``useful_bytes`` of its entries that name an active lane's block,
+        then ``kv.gather`` per tensor. A traced ``kv.h2d`` or ``kv.gather``
+        fences its own output, so it holds its own time."""
         with self._phase("kv.tables"):
             for i in active:
                 kv.ensure(i, slots[i].pos + 1 + lookahead)
             nv = max(len(kv.tables[i]) for i in active)
             nv = -(-nv // self.bcfg.view_bucket) * self.bcfg.view_bucket
             tbl = kv.view_tables(nv)
-        useful = (kv.position_bytes * sum(slots[i].pos + 1 + lookahead
-                                          for i in active)
+        useful = (tbl.itemsize * int(np.count_nonzero(tbl[active]))
                   if self._obs else 0)
+        with self._phase("kv.h2d", tensor="table", bytes=tbl.nbytes,
+                         useful_bytes=useful):
+            tbl = kv.place(tbl)
+            if self._obs:
+                jax.block_until_ready(tbl)
         views = []
         for tensor in ("k", "v"):
             with self._phase("kv.gather", tensor=tensor):
-                host = kv.gather_host(tbl, tensor, tier)
-            with self._phase("kv.h2d", tensor=tensor, bytes=host.nbytes,
-                             useful_bytes=useful):
-                views.append(kv.place(host))
+                views.append(kv.gather_view(tbl, tensor, tier))
                 if self._obs:
                     jax.block_until_ready(views[-1])
         return tuple(views)

@@ -3,11 +3,12 @@ profiler's trace.
 
 Contracts under test, on the smoke model:
 
-  * every ``step.gather`` nests ``kv.tables``, then ``kv.gather`` and
-    ``kv.h2d`` for K and then for V, inside its bounds;
-  * the ``bytes`` args of ``kv.h2d`` are the view's bytes, sum to the
-    pool's ``h2d_bytes`` counter (kept traced or not), and ``useful_bytes``
-    counts the positions the active lanes read or write;
+  * every ``step.gather`` nests ``kv.tables``, then ``kv.h2d`` of the
+    block table, then ``kv.gather`` for K and for V, inside its bounds;
+  * the ``bytes`` args of ``kv.h2d`` are the block table's bytes, sum to
+    the pool's ``h2d_bytes`` counter (kept traced or not), and
+    ``useful_bytes`` counts the table entries that name an active lane's
+    block;
   * every ``decode_step`` names the requests it gives a token (``rids``),
     so span ends rebuild each request's token gaps;
   * the phases land in a profiler session's host plane, on a clock that
@@ -80,32 +81,33 @@ def test_step_gather_nests_the_kv_stages(traced):
     for g in gathers:
         kids = sorted(_inside(evs, g), key=lambda e: e["ts"])
         assert [(e["name"], e["args"].get("tensor")) for e in kids] == [
-            ("kv.tables", None), ("kv.gather", "k"), ("kv.h2d", "k"),
-            ("kv.gather", "v"), ("kv.h2d", "v")]
+            ("kv.tables", None), ("kv.h2d", "table"), ("kv.gather", "k"),
+            ("kv.gather", "v")]
     # every kv stage of the decode path belongs to a step.gather
     assert sum(len(_named(evs, n)) for n in ("kv.tables", "kv.gather",
-                                             "kv.h2d")) == 5 * len(gathers)
+                                             "kv.h2d")) == 4 * len(gathers)
 
 
 def test_h2d_bytes_are_the_views(smoke_model, traced):
-    """Per step, 2 x L x n_slots x Sv x KV x dh x itemsize, with Sv the
-    longest active lane's blocks rounded up to the bucket; useful bytes
-    cover positions [0, pos] of the active lanes."""
+    """Per step, one int32 block table of n_slots x n_view entries, with
+    n_view the longest active lane's blocks rounded up to the bucket;
+    useful bytes are the entries that name the active lanes' blocks
+    (positions [0, pos] of each)."""
     cfg, _ = smoke_model
     rep, evs = traced
     prompt = {r.rid: len(r.prompt) for r in _reqs(cfg)}
-    per_pos = cfg.n_layers * cfg.n_kv_heads_eff * cfg.dh * 4  # float32
     steps_done = {rid: 0 for rid in prompt}
     total = 0
     for step, g in zip(_named(evs, "decode_step"), _named(evs, "step.gather")):
         pos = [prompt[r] + steps_done[r] for r in step["args"]["rids"]]
-        blocks = max(-(-(p + 1) // BLOCK) for p in pos)
-        sv = -(-blocks // BUCKET) * BUCKET * BLOCK
+        held = [-(-(p + 1) // BLOCK) for p in pos]
+        n_view = -(-max(held) // BUCKET) * BUCKET
         h2d = _named(_inside(evs, g), "kv.h2d")
+        assert len(h2d) == 1
         for e in h2d:
-            assert e["args"]["bytes"] == N_SLOTS * sv * per_pos
-            assert e["args"]["useful_bytes"] == sum(p + 1 for p in pos) \
-                * per_pos <= e["args"]["bytes"]
+            assert e["args"]["bytes"] == N_SLOTS * n_view * 4
+            assert 0 < e["args"]["useful_bytes"] == 4 * sum(held) \
+                <= e["args"]["bytes"]
         total += sum(e["args"]["bytes"] for e in h2d)
         for r in step["args"]["rids"]:
             steps_done[r] += 1
@@ -166,7 +168,7 @@ def test_phases_land_in_the_profiler_trace(smoke_model, tmp_path):
     assert sorted(dict(e.stats)["step_num"] for e in steps) == list(
         range(len(steps)))
     h2d = [dict(e.stats) for e in host if e.name == "kv.h2d"]
-    assert all(s["bytes"] > 0 and s["tensor"] in ("k", "v") for s in h2d)
+    assert all(s["bytes"] > 0 and s["tensor"] == "table" for s in h2d)
 
 
 def test_launch_serve_profile_holds_the_phases(tmp_path):

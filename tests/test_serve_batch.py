@@ -393,3 +393,174 @@ def test_debug_poison_fills_freed_blocks_with_nan(dense_model):
     for b in held:
         assert np.all(np.isnan(kv.pool_k[0, b]))
         assert np.all(np.isnan(kv.pool_v[0, b]))
+
+
+# ---------------------------------------------------------------------------
+# The pool on the device
+# ---------------------------------------------------------------------------
+
+
+def _filled_pool(cfg, tiers=2):
+    """A pool whose every (tier, block) holds its own random K/V."""
+    kv = PagedKVCache(cfg, n_slots=3, n_blocks=10, block_size=4, tiers=tiers)
+    rng = np.random.default_rng(11)
+    for tier in range(tiers):
+        k = rng.standard_normal(
+            (cfg.n_layers, 9 * 4, cfg.n_kv_heads_eff, cfg.dh)).astype(
+                np.float32)
+        kv.write_prefill(0, jnp.asarray(k), jnp.asarray(-k), 9 * 4,
+                         tier=tier)
+    return kv
+
+
+def _reference_view(pool, tbl, tier):
+    g = np.asarray(pool)[tier][tbl]  # (B, n_view, L, bs, KV, dh)
+    b, n_view, n_layers, bs, n_kv, dh = g.shape
+    return g.transpose(2, 0, 1, 3, 4, 5).reshape(n_layers, b, n_view * bs,
+                                                 n_kv, dh)
+
+
+@pytest.mark.parametrize("tier", [0, 1])
+@pytest.mark.parametrize("slots", [None, [1], [2, 0]],
+                         ids=["all", "one", "two"])
+def test_device_gather_matches_a_numpy_gather(dense_model, tier, slots):
+    """The device gather takes exactly the rows a numpy gather of the same
+    pool takes: random tables, rows padded with the scratch block."""
+    cfg, _ = dense_model
+    kv = _filled_pool(cfg)
+    assert isinstance(kv.pool_k, jax.Array)
+    assert isinstance(kv.pool_v, jax.Array)
+    rng = np.random.default_rng(tier)
+    owned = rng.permutation(kv.tables[0])
+    kv.tables = [list(owned[:3]), list(owned[3:4]), list(owned[4:9])]
+    for n_view in (2, 6):
+        tbl = kv.view_tables(n_view, slots)
+        assert n_view == 2 or (tbl == 0).any()  # scratch-padded rows
+        got_k, got_v = kv.gather(n_view, tier=tier, slots=slots)
+        np.testing.assert_array_equal(
+            np.asarray(got_k), _reference_view(kv.pool_k, tbl, tier))
+        np.testing.assert_array_equal(
+            np.asarray(got_v), _reference_view(kv.pool_v, tbl, tier))
+
+
+def test_operand_committed_to_a_device_leaves_the_pool_uncommitted(
+        dense_model):
+    """K/V committed to a device (a gateway's dedicated prefill device)
+    land in the pool without committing it, so its views stay uncommitted
+    like any view the decode program was warmed with."""
+    cfg, _ = dense_model
+    kv = PagedKVCache(cfg, n_slots=1, n_blocks=4, block_size=4)
+    x = np.arange(cfg.n_layers * 4 * cfg.n_kv_heads_eff * cfg.dh,
+                  dtype=np.float32).reshape(cfg.n_layers, 4,
+                                            cfg.n_kv_heads_eff, cfg.dh)
+    kv.write_prefill(0, jax.device_put(x, jax.devices()[0]),
+                     jax.device_put(-x, jax.devices()[0]), 4)
+    got_k, got_v = kv.gather(1)
+    assert not kv.pool_k.committed and not got_k.committed
+    np.testing.assert_array_equal(np.asarray(got_k)[:, 0], x)
+    np.testing.assert_array_equal(np.asarray(got_v)[:, 0], -x)
+
+
+def test_h2d_bytes_count_the_block_tables(dense_model, monkeypatch):
+    """What a served run places on the device for its gathers is their
+    block tables, and nothing of the K/V views."""
+    cfg, params = dense_model
+    placed = []
+    real = PagedKVCache.view_tables
+
+    def recorded(self, *a, **k):
+        tbl = real(self, *a, **k)
+        placed.append(tbl.nbytes)
+        return tbl
+
+    monkeypatch.setattr(PagedKVCache, "view_tables", recorded)
+    srv = BatchServer(cfg, DP.from_params(cfg, params), ServeConfig(),
+                      BatchConfig(n_slots=3, block_size=4, n_blocks=32),
+                      engine="scan")
+    rep = srv.run(_trace(cfg))
+    assert rep.n_decode_steps > 0
+    assert rep.kv_stats["h2d_bytes"] == sum(placed)
+    # one (n_slots, n_view) int32 table per step: 3 slots x at most 6 blocks
+    assert rep.kv_stats["h2d_bytes"] <= rep.n_decode_steps * 3 * 6 * 4
+
+
+class _Compiles:
+    """Backend compiles and persistent-cache loads while the block runs."""
+
+    def __enter__(self):
+        self.n = 0
+        jax.monitoring.register_event_duration_secs_listener(self._compiled)
+        jax.monitoring.register_event_listener(self._loaded)
+        return self
+
+    def _compiled(self, event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.n += 1
+
+    def _loaded(self, event, **kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.n += 1
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._compiled)
+        jax.monitoring.unregister_event_listener(self._loaded)
+
+
+def _exercise_pool(kv, slot, tier, start):
+    """Every program of the pool once: a prefill, a shared block written
+    (copy-on-write), a decode step's write, a run, a gather, the scrub."""
+    cfg = kv.cfg
+    rng = np.random.default_rng(start)
+    x = rng.standard_normal((cfg.n_layers, 8, cfg.n_kv_heads_eff,
+                             cfg.dh)).astype(np.float32)
+    kv.write_prefill(slot, jnp.asarray(x), jnp.asarray(x), 7, tier=tier,
+                     start=start)
+    other = 1 - slot
+    kv.adopt(other, kv.tables[slot][start // kv.block_size:])
+    positions = [None, None]
+    positions[other] = 1
+    pb, off = kv.write_coords(positions)
+    kv.write_token(pb, off, x[:, :2], x[:, :2], tier=tier)
+    kv.write_run(slot, start + 2, x[:, :3], x[:, :3], tier=tier)
+    kv.gather(6, tier=tier)
+    kv.free_slot(0)
+    kv.free_slot(1)
+
+
+def test_pool_programs_take_ids_as_operands(dense_model):
+    """Other blocks, offsets, tier and scrub value than the first time
+    compile nothing: none of them is a static argument."""
+    cfg, _ = dense_model
+    first = PagedKVCache(cfg, n_slots=2, n_blocks=12, block_size=4, tiers=2)
+    _exercise_pool(first, slot=0, tier=0, start=0)
+    again = PagedKVCache(cfg, n_slots=2, n_blocks=12, block_size=4, tiers=2,
+                         debug_poison=True)
+    again.ensure(0, 4)  # the exercise's blocks move down the free list
+    with _Compiles() as c:
+        _exercise_pool(again, slot=1, tier=1, start=4)
+    assert c.n == 0
+
+
+@pytest.mark.parametrize("engine", ["loop", "scan", "spec"])
+def test_second_identical_run_compiles_nothing(dense_model, engine):
+    """The pool's programs take block ids, offsets, tiers and fill values
+    as operands: a second run of the same trace on the same server finds
+    every program it needs compiled."""
+    from repro.serve.spec import SpecConfig
+
+    cfg, params = dense_model
+    sp = DP.from_params(cfg, params)
+    spec = {"draft": sp, "spec": SpecConfig(k=2, draft_sparsity=0.5)} \
+        if engine == "spec" else {}
+    srv = BatchServer(cfg, sp, ServeConfig(),
+                      BatchConfig(n_slots=2, block_size=4, n_blocks=24),
+                      engine=engine, **spec)
+    jax.clear_caches()  # what earlier tests compiled is compiled again
+    with _Compiles() as c:
+        first = srv.run(_trace(cfg, n=5, seed=9))
+    assert c.n > 0
+    with _Compiles() as c:
+        second = srv.run(_trace(cfg, n=5, seed=9))
+    assert c.n == 0
+    for rid, out in first.outputs.items():
+        np.testing.assert_array_equal(second.outputs[rid], out)
